@@ -10,10 +10,11 @@ ways and emits ``BENCH_campaign.json``:
   the host devices, ``kset`` members vmapped per device, one compiled
   chunk program reused across every round.
 
-Throughput is cases/s over the whole ensemble.  On this CPU container the
-devices are virtual (``--xla_force_host_platform_device_count``), so the
-win comes from batching + single-compilation amortization rather than real
-parallel silicon; on a TPU/GPU mesh the same file measures real scaling.
+Throughput is cases/s over the whole ensemble.  The bench runs on the CPU
+backend only (it refuses any other): the devices are virtual
+(``--xla_force_host_platform_device_count``), so the win comes from
+batching + single-compilation amortization rather than real parallel
+silicon, and none of its numbers is an accelerator measurement.
 
 ``--processes N`` adds a **multi-host scaling** section: the bench respawns
 itself as N ``jax.distributed`` CPU processes (1 forced host device each)
@@ -180,6 +181,12 @@ def main(argv=None):
         args.waves, args.nt = 4, 6
     if args.dist_child:
         return _dist_child(args)
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"campaign_bench times the CPU rehearsal (virtual host devices, "
+            f"CPU worker processes); on this {jax.default_backend()} host its "
+            f"numbers would not be the accelerator's.  Run it with "
+            f"JAX_PLATFORMS=cpu; measure the accelerator with chip_smoke.py")
 
     n_dev = min(args.devices, len(jax.devices()))
     mesh = meshgen.generate(*(int(x) for x in args.mesh_n.split("x")), pad_elems_to=8)

@@ -106,6 +106,14 @@ def main(argv=None):
         args.nt = min(args.nt, 8)
         args.train_steps = min(args.train_steps, 20)
         args.reps = 1
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"scheduler_bench times CPU worker pools; on this "
+            f"{jax.default_backend()} host its numbers would not be the "
+            f"accelerator's, and the launcher refuses a second chip-holding "
+            f"worker.  Run it with JAX_PLATFORMS=cpu")
     sweep = _sweep_json(args)
     work = tempfile.mkdtemp(prefix="sched_bench_")
     cores = os.cpu_count() or 1

@@ -58,7 +58,7 @@ def main(n: int = 3, nspring: int = 12):
     t_solve_ebe = _time(fcg, b)
 
     # phase: multispring (resident vs streamed)
-    eps = spmv.strain_at_points(jax.random.normal(jax.random.key(1), (mesh.n_nodes, 3), cfg.dtype) * 1e-4, mesh)
+    eps = spmv.strain_at_points(jax.random.normal(jax.random.key(1), (mesh.n_nodes, 3), cfg.dtype) * 1e-4, ops.points)
     ms_res = jax.jit(lambda e, s: ops.multispring_all(e, s))
     t_ms = _time(ms_res, eps, springs)
 

@@ -56,12 +56,7 @@ def with_memory_kind(sharding, kind: str):
     return sharding.with_memory_kind(kind)
 
 
-try:  # newer jax exposes memory spaces as a public enum
-    _SPACE = {DEVICE: jax.memory.Space.Device, HOST: jax.memory.Space.Host}
-except AttributeError:  # jax ≤ 0.4.x: string memory kinds via device_put targets
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-    _SPACE = {DEVICE: TransferToMemoryKind(DEVICE), HOST: TransferToMemoryKind(HOST)}
+_SPACE = {DEVICE: jax.memory.Space.Device, HOST: jax.memory.Space.Host}
 
 
 @functools.lru_cache(maxsize=1)
@@ -70,56 +65,36 @@ def _runtime_kinds() -> frozenset:
 
 
 def transfers_supported() -> bool:
-    """True when the runtime distinguishes device vs host memory kinds.
+    """True when the runtime has a host memory kind to stream θ through.
 
-    The CPU test runtime advertises a single memory (``unpinned_host``); the
-    placements of Algorithm 3 are then *annotations* — semantically exact,
-    physically no-ops — and :func:`transfer` elides them entirely.
+    Every backend of the installed JAX (CPU, TPU, GPU) advertises
+    ``device`` and ``pinned_host``; the memory space is part of an array's
+    type (``float32<host>``), so the placements of Algorithm 3 are real
+    copies on all of them.
     """
     return HOST in _runtime_kinds()
 
 
-def _space_for(kind: str):
-    space = _SPACE.get(kind)
-    if space is not None:
-        return space
-    try:  # arbitrary advertised kinds (e.g. "unpinned_host") → string target
-        from jax._src.sharding_impls import TransferToMemoryKind
-
-        return TransferToMemoryKind(kind)
-    except ImportError:  # pragma: no cover - no string targets on this jax
-        return None
-
-
 def transfer_is_real(kind: str) -> bool:
     """True when :func:`transfer` to ``kind`` stages an actual copy."""
-    return kind in _runtime_kinds() and _space_for(kind) is not None
+    return kind in _SPACE and kind in _runtime_kinds()
 
 
 def transfer(tree: Any, kind: str) -> Any:
     """Stage a memory-space transfer for every leaf of ``tree`` inside jit.
 
-    On single-memory runtimes (CPU test env) this is the identity: the
-    streamed loop keeps its exact trace order, only the copies vanish.
+    Raises ``ValueError`` for a kind the runtime does not advertise: a
+    streamed pass that silently kept θ where it was would hide the very
+    placement it exists to make.
     """
-    if kind not in _runtime_kinds():
-        return tree
-    space = _space_for(kind)
-    if space is None:
-        return tree
-
-    def put(x):
-        try:
-            return jax.device_put(x, space)
-        except ValueError:
-            # string-kind targets are jit-only; eagerly use a concrete sharding
-            sh = SingleDeviceSharding(jax.devices()[0], memory_kind=kind)
-            return jax.device_put(x, sh)
-
-    return jax.tree_util.tree_map(put, tree)
-
-
-_transfer = transfer  # backwards-compatible alias
+    if not transfer_is_real(kind):
+        raise ValueError(
+            f"memory kind {kind!r} cannot be a transfer target here "
+            f"(runtime advertises {sorted(_runtime_kinds())}, transfers "
+            f"target {sorted(_SPACE)})"
+        )
+    space = _SPACE[kind]
+    return jax.tree_util.tree_map(lambda x: jax.device_put(x, space), tree)
 
 
 def to_device(tree: Any) -> Any:
@@ -134,10 +109,14 @@ def put_host(tree: Any, sharding=None) -> Any:
     """Eagerly place ``tree`` in host memory (outside jit).
 
     ``sharding`` may be a distributed sharding; defaults to the default
-    device's host memory.  Identity on runtimes without a host memory kind.
+    device's host memory.  Raises ``ValueError`` on a runtime without a
+    host memory kind.
     """
     if not host_memory_available():
-        return tree
+        raise ValueError(
+            f"no {HOST!r} memory on this runtime "
+            f"(advertises {list(supported_memory_kinds())})"
+        )
     if sharding is None:
         sharding = SingleDeviceSharding(jax.devices()[0], memory_kind=HOST)
     else:

@@ -1,7 +1,7 @@
 """Kernel-backend dispatch: put the Pallas kernels on the production hot path.
 
 The repo carries two implementations of each FEM hotspot — the pure-jnp
-oracle (``fem/spmv.ebe_element_matvec``, ``fem/multispring.update``) and the
+oracle (``fem/spmv.ebe_element_lanes``, ``fem/multispring.update``) and the
 hand-tuned Pallas kernels (``kernels/ebe_matvec``, ``kernels/multispring``).
 Until this module existed, only tests ever ran the Pallas side: every
 production path constructed ``FemOperators(mesh, cfg)`` bare, which means

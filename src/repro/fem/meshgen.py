@@ -75,8 +75,8 @@ class Mesh:
     wdet: np.ndarray          # [E,P]
     # scatter maps
     elem_dofs: np.ndarray     # [E,30] int32
-    scatter_perm: np.ndarray  # [E*30] int32 argsort of elem_dofs.ravel()
-    scatter_segids: np.ndarray  # [E*30] int32 sorted dof ids
+    node_perm: np.ndarray     # [10*E] int32 argsort of conn.T.ravel()
+    node_segids: np.ndarray   # [10*E] int32 sorted node ids
     # BCSR 3x3 (node blocks)
     row_ptr: np.ndarray       # [N+1] int32
     col_idx: np.ndarray       # [nnzb] int32
@@ -219,9 +219,9 @@ def generate(
 
     # --- scatter maps
     elem_dofs = (3 * conn[:, :, None] + np.arange(3)[None, None]).reshape(E, 30).astype(np.int32)
-    flat = elem_dofs.ravel()
-    scatter_perm = np.argsort(flat, kind="stable").astype(np.int32)
-    scatter_segids = flat[scatter_perm].astype(np.int32)
+    flat = conn.T.ravel()  # (node slot, element): the lane-major order
+    node_perm = np.argsort(flat, kind="stable").astype(np.int32)
+    node_segids = flat[node_perm].astype(np.int32)
 
     # --- BCSR (node-block) sparsity from real (unpadded) elements
     ii = np.repeat(conn[:E0], 10, axis=1).ravel()
@@ -249,8 +249,8 @@ def generate(
         detJ=detJ,
         wdet=wdet,
         elem_dofs=elem_dofs,
-        scatter_perm=scatter_perm,
-        scatter_segids=scatter_segids,
+        node_perm=node_perm,
+        node_segids=node_segids,
         row_ptr=row_ptr,
         col_idx=cols,
         rowids=rows,

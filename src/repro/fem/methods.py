@@ -9,10 +9,8 @@
                                preconditioner, no CRS update; supports ≥2
                                ensemble sets resident (2SET) via vmap
 
-All four advance the same physics; tests assert trajectory equality.  On
-this CPU container the memory *placements* are annotations (no-ops for
-speed, correct for semantics); on a GH200/TPU runtime they are real, and
-the modeled device timings come from core/pipeline.py.
+All four advance the same physics; tests assert trajectory equality.  The
+memory placements are real copies on every backend (DESIGN.md §6).
 """
 from __future__ import annotations
 
@@ -107,6 +105,7 @@ class FemOperators:
         self.w_dirs = jnp.asarray(w, dt)
         self.params, self.h_max = _material_tables(mesh, cfg)
         self.nnzb = mesh.col_idx.shape[0]
+        self.points = spmv.point_tables(mesh, dt)
         self.element_kernel = element_kernel
         self.multispring_fn = multispring_fn or ms.update
         # set by fem.backend.make_operators — None means "constructed bare"
@@ -167,23 +166,23 @@ class FemOperators:
     # ---- operators ---------------------------------------------------------
     def crs_update(self, D, beta_e, alpha):
         """UpdateCRS: assemble A's BCSR values + block-Jacobi inverse."""
-        K_e = assembly.element_stiffness(D, self.Jinv, self.wdet)
+        Kb = assembly.element_blocks(D, self.Jinv, self.wdet)    # 9 × [10,10,E]
         coef = 1.0 + (2.0 / self.cfg.dt) * beta_e
-        valA = assembly.assemble_bcsr(K_e * coef[:, None, None], self.mesh.entry_map, self.nnzb)
+        valA = assembly.assemble_bcsr([K * coef for K in Kb], self.mesh.entry_map, self.nnzb)
         diag_add = (
             (4.0 / self.cfg.dt**2 + 2.0 * alpha / self.cfg.dt) * self.mass[:, None]
             + (2.0 / self.cfg.dt) * self.dash
         )
         valA = assembly.add_diag(valA, self.mesh.diag_slots, diag_add)
         # separate K values for C·v in the RHS (β-weighted) — the damping matvec
-        valCk = assembly.assemble_bcsr(K_e * beta_e[:, None, None], self.mesh.entry_map, self.nnzb)
+        valCk = assembly.assemble_bcsr([K * beta_e for K in Kb], self.mesh.entry_map, self.nnzb)
         Minv = assembly.block_jacobi_inverse(valA, self.mesh.diag_slots)
         return valA, valCk, Minv
 
     def crs_matvec(self, valA):
         def mv(xflat):
-            x = xflat.reshape(-1, 3)
-            return spmv.bcsr_matvec(valA, self.mesh.rowids, self.mesh.col_idx, x).reshape(-1)
+            x = solver.unflat(xflat)
+            return solver.flat(spmv.bcsr_matvec(valA, self.mesh.rowids, self.mesh.col_idx, x))
         return mv
 
     def cv_matvec_crs(self, valCk, alpha):
@@ -202,12 +201,12 @@ class FemOperators:
         def mv(xflat):
             # dtype-follows-input: the same closure serves the fp64 outer CG
             # and the fp32 inner preconditioner (mixed precision, paper [9])
-            x = xflat.reshape(-1, 3)
+            x = solver.unflat(xflat)
             y = spmv.ebe_matvec(
                 x, D.astype(x.dtype), self.mesh, coef.astype(x.dtype),
                 element_kernel=self.element_kernel,
             )
-            return (y + diag.astype(x.dtype) * x).reshape(-1)
+            return solver.flat(y + diag.astype(x.dtype) * x)
 
         return mv
 
@@ -218,22 +217,26 @@ class FemOperators:
         return mv
 
     def ebe_diag_inverse(self, D, beta_e, alpha):
-        """Block-Jacobi of A without assembling K (nodal diag blocks only)."""
-        B = assembly.b_matrices(self.Jinv)  # [E,P,6,30]
-        Bn = B.reshape(B.shape[0], B.shape[1], 6, quad.NNODE, 3)
+        """Block-Jacobi of A without assembling K (nodal diag blocks only),
+        lane-major ``[9, N]`` like :func:`assembly.block_jacobi_inverse`.
+
+        The diagonal blocks (n = m) of :func:`assembly.element_blocks`, with
+        the element index minor, so no ``[E, small, small]`` product is
+        formed (see assembly.py)."""
+        dt = D.dtype
         coef = 1.0 + (2.0 / self.cfg.dt) * beta_e
-        w = self.wdet * coef[:, None]
-        Kdiag = jnp.einsum("ep,epkna,epkl,eplnb->enab", w, Bn, D, Bn)  # [E,10,3,3]
+        K = assembly.element_blocks(D, self.Jinv, self.wdet * coef[:, None],
+                                    diagonal=True)            # 9 × [10,E]
         N = self.mesh.n_nodes
-        flat = Kdiag.reshape(-1, 9)
-        idx = jnp.asarray(self.mesh.conn.reshape(-1))
-        nodal = jax.ops.segment_sum(flat, idx, num_segments=N).reshape(N, 3, 3)
+        idx = jnp.asarray(self.mesh.conn.T.reshape(-1))       # (node slot, elem)
         diag_add = (
             (4.0 / self.cfg.dt**2 + 2.0 * alpha / self.cfg.dt) * self.mass[:, None]
             + (2.0 / self.cfg.dt) * self.dash
-        )
-        nodal = nodal + diag_add[:, :, None] * jnp.eye(3, dtype=nodal.dtype)[None]
-        return jnp.linalg.inv(nodal)
+        ).astype(dt)                                          # [N,3]
+        nodal = [jnp.zeros(N, dt).at[idx].add(K[3 * a + b].reshape(-1))
+                 + (diag_add[:, a] if a == b else 0.0)
+                 for a in range(3) for b in range(3)]
+        return assembly.inv3(nodal)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +245,7 @@ class FemOperators:
 
 
 def _strain_pts(ops, u):
-    return spmv.strain_at_points(u, ops.mesh)
+    return spmv.strain_at_points(u, ops.points)
 
 
 def _resident_multispring(ops, eps_pts, springs):
@@ -313,13 +316,13 @@ def make_step_crs(ops: FemOperators, *, transfer_boundaries: bool = False,
         b = newmark.rhs(nm, f_ext, ops.mass, cfg.dt, ops.cv_matvec_crs(valCk, alpha))
         res = solver.pcg(
             ops.crs_matvec(valA),
-            b.reshape(-1),
+            solver.flat(b),
             solver.block_jacobi_apply(Minv),
             tol=cfg.tol,
             maxiter=cfg.maxiter,
             x0=x0,
         )
-        du = res.x.reshape(-1, 3)
+        du = solver.unflat(res.x)
         u_new = nm.u + du
         eps_pts = _strain_pts(ops, u_new)
         if streamed:
@@ -337,7 +340,7 @@ def make_step_crs(ops: FemOperators, *, transfer_boundaries: bool = False,
             sigma, D_new = hetmem.to_device((sigma, D_new))
         else:
             sigma, D_new, springs = _resident_multispring(ops, eps_pts, springs)
-        q_new = spmv.internal_force(sigma, ops.mesh)
+        q_new = spmv.internal_force(sigma, ops.points)
         nm = newmark.advance(nm, du, q_new, cfg.dt)
         if streamed:
             alpha, beta_e = ops.damping_from_frac(frac)
@@ -392,8 +395,8 @@ def make_step_ebe(ops: FemOperators, *, streamed: bool = True, offload: bool = T
         )
         f_ext = ops.force_map * f_t[None, :]
         b = newmark.rhs(nm, f_ext, ops.mass, cfg.dt, ops.cv_matvec_ebe(D, beta_e, alpha))
-        res = solver.fcg(mvA, b.reshape(-1), inner, tol=cfg.tol, maxiter=cfg.maxiter, x0=x0)
-        du = res.x.reshape(-1, 3)
+        res = solver.fcg(mvA, solver.flat(b), inner, tol=cfg.tol, maxiter=cfg.maxiter, x0=x0)
+        du = solver.unflat(res.x)
         u_new = nm.u + du
         eps_pts = _strain_pts(ops, u_new)
         if streamed:
@@ -404,7 +407,7 @@ def make_step_ebe(ops: FemOperators, *, streamed: bool = True, offload: bool = T
         else:
             sigma, D_new, springs = _resident_multispring(ops, eps_pts, springs)
             alpha, beta_e = ops.damping_coeffs(springs)
-        q_new = spmv.internal_force(sigma, ops.mesh)
+        q_new = spmv.internal_force(sigma, ops.points)
         nm = newmark.advance(nm, du, q_new, cfg.dt)
         tail = (res.x,) if cfg.warm_start else ()
         if lag:
@@ -440,7 +443,7 @@ def initial_carry(ops: FemOperators, *, streamed: bool = False, host: bool = Tru
     if cfg.warm_start:
         tail += (jnp.zeros(3 * ops.mesh.n_nodes, cfg.rdtype),)
     if ebe and cfg.precond_every > 1:
-        tail += (jnp.zeros((ops.mesh.n_nodes, 3, 3), cfg.rdtype),
+        tail += (jnp.zeros((9, ops.mesh.n_nodes), cfg.rdtype),
                  jnp.zeros((), jnp.int32))
     if streamed:
         ps = partition_springs(ops, springs, cfg.npart)
@@ -487,7 +490,9 @@ def run(
         mesh, cfg, element_kernel=element_kernel, multispring_fn=multispring_fn
     )
     step, streamed = make_step(method, ops, offload=offload)
-    carry = initial_carry(ops, streamed=streamed, ebe=method == "proposed2")
+    carry = initial_carry(
+        ops, streamed=streamed, host=offload, ebe=method == "proposed2"
+    )
     obs_idx = jnp.asarray(observe if observe is not None else mesh.surface[:1])
 
     @jax.jit
@@ -505,6 +510,7 @@ def run(
         "velocity_history": vel,  # [nt, n_obs, 3]
         "iters": auxes.iters,
         "relres": auxes.relres,
+        "springs": carry[1],  # final θ: host blocks when streamed + offload
     }
 
 

@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the spring sums are MXU matmuls; on TPU an fp32 dot defaults to one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
+
 NSPRING_DEFAULT = 150
 
 
@@ -126,7 +129,7 @@ def update(
     gr = params.gamma_r[:, None]
     be = params.beta[:, None]
 
-    gamma = eps @ n.T  # [P,S]
+    gamma = jnp.matmul(eps, n.T, precision=HIGHEST)  # [P,S]
     g_prev = state["gamma_prev"]
     dgam = gamma - g_prev
     moving = jnp.sign(dgam).astype(jnp.int32)
@@ -162,11 +165,11 @@ def update(
 
     # assemble stress and consistent tangent
     tw = tau * w[None, :]                       # [P,S]
-    sigma_dev = tw @ n                          # [P,6]
+    sigma_dev = jnp.matmul(tw, n, precision=HIGHEST)  # [P,6]
     gw = g_tan * w[None, :]
     # D_dev[p,a,b] = Σ_s gw[p,s] n[s,a] n[s,b]  — an MXU matmul over S
     nn = n[:, :, None] * n[:, None, :]          # [S,6,6]
-    D_dev = jnp.einsum("ps,sab->pab", gw, nn)
+    D_dev = jnp.einsum("ps,sab->pab", gw, nn, precision=HIGHEST)
 
     vol_eps = eps[:, :3].sum(axis=1)
     one = jnp.array([1.0, 1.0, 1.0, 0.0, 0.0, 0.0], eps.dtype)

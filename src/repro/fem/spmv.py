@@ -9,16 +9,20 @@ precomputed permutation (deterministic, TPU-idiomatic).
 The jnp implementations here are the reference path; kernels/ebe_matvec
 holds the Pallas kernel for the per-element contraction (the flop hotspot),
 wired in through the same gather/scatter maps whenever the dispatch layer
-(repro.fem.backend) resolves to it.
+(repro.fem.backend) resolves to it.  Contractions over the small tensor
+axes are elementwise multiply-and-sum, never ``dot``: exact fp32 on every
+backend (a TPU fp32 dot defaults to one bf16 pass).
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fem import quadrature as quad
-from repro.fem.assembly import physical_gradients_jnp
+from repro.fem import assembly, quadrature as quad
+from repro.fem.assembly import block_entries
 
 
 # ---------------------------------------------------------------------------
@@ -27,39 +31,53 @@ from repro.fem.assembly import physical_gradients_jnp
 
 
 def bcsr_matvec(
-    values: jnp.ndarray,  # [nnzb,3,3]
-    rowids: np.ndarray,   # [nnzb]
+    values: jnp.ndarray,  # [9*nnzb] BCSR 3×3 values (assembly.assemble_bcsr)
+    rowids: np.ndarray,   # [nnzb] sorted
     col_idx: np.ndarray,  # [nnzb]
     x: jnp.ndarray,       # [N,3]
 ) -> jnp.ndarray:
-    """y[i] = Σ_j A[i,j] x[j] with 3×3 blocks (gather + segment-sum)."""
-    xj = x[jnp.asarray(col_idx)]                      # [nnzb,3]
-    prod = jnp.einsum("nab,nb->na", values, xj)       # [nnzb,3]
-    return jax.ops.segment_sum(prod, jnp.asarray(rowids), num_segments=x.shape[0])
+    """y[i] = Σ_j A[i,j] x[j] with 3×3 blocks (gather + sorted scatter-add),
+    one flat vector per component so nothing is laid out with a minor 3."""
+    cols, rows = jnp.asarray(col_idx), jnp.asarray(rowids)
+    xj = [x[:, b][cols] for b in range(3)]             # 3 × [nnzb]
+    y = [
+        jnp.zeros(x.shape[0], x.dtype).at[rows].add(
+            sum(block_entries(values, 3 * a + b) * xj[b] for b in range(3)),
+            indices_are_sorted=True)
+        for a in range(3)
+    ]
+    return jnp.stack(y, axis=1)
 
 
 # ---------------------------------------------------------------------------
-# shared gather / scatter machinery
+# shared gather / scatter machinery (element index minor)
 # ---------------------------------------------------------------------------
+#
+# Per-element quantities are laid out with the element index on the minor
+# axis — nodal values ``[10, 3, E]``, strains and stresses ``[P, 6, E]`` —
+# and the small tensor dimensions are leading axes.  A TPU tiles the two
+# minor axes of an array to (8, 128): an ``[E, 10, 3]`` array pads ~70-fold
+# in HBM, and the XLA programs that gather into or reshape through such
+# layouts compile about ten times slower than this form.
 
 
-def gather_elem(u: jnp.ndarray, conn: np.ndarray) -> jnp.ndarray:
-    """Nodal values per element ``[E,10,3]`` from ``u [N,3]``."""
-    return u[jnp.asarray(conn)]
+def gather_nodal(u: jnp.ndarray, mesh) -> jnp.ndarray:
+    """Nodal values per element ``[10, 3, E]`` from ``u [N,3]``."""
+    idx = jnp.asarray(mesh.conn.T.reshape(-1))  # (node slot, element)
+    E = mesh.n_elem
+    return jnp.stack([u[:, a][idx].reshape(quad.NNODE, E) for a in range(3)], axis=1)
 
 
-def scatter_add(
-    f_e: jnp.ndarray,          # [E,10,3]
-    scatter_perm: np.ndarray,  # [E*30]
-    scatter_segids: np.ndarray,
-    ndof: int,
-) -> jnp.ndarray:
-    """Σ per dof via sorted segment-sum (atomic-add replacement) → [N,3]."""
-    flat = f_e.reshape(-1)[jnp.asarray(scatter_perm)]
-    y = jax.ops.segment_sum(
-        flat, jnp.asarray(scatter_segids), num_segments=ndof, indices_are_sorted=True
-    )
-    return y.reshape(-1, 3)
+def scatter_nodal(f: jnp.ndarray, mesh) -> jnp.ndarray:
+    """Σ of element contributions ``f [10, 3, E]`` per node → ``[N,3]``, by
+    a sorted segment-sum per component (the atomic-add replacement:
+    deterministic, TPU-idiomatic)."""
+    perm, seg = jnp.asarray(mesh.node_perm), jnp.asarray(mesh.node_segids)
+    return jnp.stack([
+        jax.ops.segment_sum(f[:, a].reshape(-1)[perm], seg,
+                            num_segments=mesh.n_nodes, indices_are_sorted=True)
+        for a in range(3)
+    ], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -67,48 +85,45 @@ def scatter_add(
 # ---------------------------------------------------------------------------
 
 
-def elem_strain(u_e: jnp.ndarray, Jinv: jnp.ndarray) -> jnp.ndarray:
-    """Voigt strain at Gauss points ``[E,P,6]`` from ``u_e [E,10,3]``.
+def strain_lanes(u: jnp.ndarray, Jinv: jnp.ndarray) -> jnp.ndarray:
+    """Voigt strain at Gauss points ``[P, 6, E]`` from ``u [10, 3, E]``.
 
     ε = sym(∇u); engineering shear (γ = 2ε_offdiag) to match B-matrices.
     """
-    g = physical_gradients_jnp(Jinv)                  # [E,P,10,3]
-    H = jnp.einsum("epnj,eni->epij", g, u_e)          # ∂u_i/∂x_j
-    exx, eyy, ezz = H[..., 0, 0], H[..., 1, 1], H[..., 2, 2]
-    gxy = H[..., 0, 1] + H[..., 1, 0]
-    gyz = H[..., 1, 2] + H[..., 2, 1]
-    gzx = H[..., 2, 0] + H[..., 0, 2]
-    return jnp.stack([exx, eyy, ezz, gxy, gyz, gzx], axis=-1)
+    g = assembly.lane_major_gradients(Jinv)                     # [P,3,10,E]
+    # H[p,i,j] = ∂u_i/∂x_j = Σ_n u[n,i] g[p,j,n]
+    H = (u[None, :, :, None, :] * jnp.swapaxes(g, 1, 2)[:, :, None]).sum(axis=1)
+    return jnp.stack([H[:, 0, 0], H[:, 1, 1], H[:, 2, 2],
+                      H[:, 0, 1] + H[:, 1, 0],
+                      H[:, 1, 2] + H[:, 2, 1],
+                      H[:, 2, 0] + H[:, 0, 2]], axis=1)
 
 
-def elem_internal_force(
-    sigma: jnp.ndarray,  # [E,P,6] Voigt stress at Gauss points
-    Jinv: jnp.ndarray,
-    wdet: jnp.ndarray,   # [E,P]
-) -> jnp.ndarray:
-    """f_e ``[E,10,3]`` = Σ_p wdet_p B_pᵀ σ_p, via the ∇N contraction."""
-    g = physical_gradients_jnp(Jinv)  # [E,P,10,3]
-    s = sigma * wdet[..., None]       # fold weights
-    # Voigt → tensor rows: f[n,i] = Σ_p σ_ij(p) ∂N_n/∂x_j
-    sxx, syy, szz, sxy, syz, szx = (s[..., k] for k in range(6))
-    fx = jnp.einsum("epn,ep->en", g[..., 0], sxx) + jnp.einsum("epn,ep->en", g[..., 1], sxy) + jnp.einsum("epn,ep->en", g[..., 2], szx)
-    fy = jnp.einsum("epn,ep->en", g[..., 0], sxy) + jnp.einsum("epn,ep->en", g[..., 1], syy) + jnp.einsum("epn,ep->en", g[..., 2], syz)
-    fz = jnp.einsum("epn,ep->en", g[..., 0], szx) + jnp.einsum("epn,ep->en", g[..., 1], syz) + jnp.einsum("epn,ep->en", g[..., 2], szz)
-    return jnp.stack([fx, fy, fz], axis=-1)
+def force_lanes(s: jnp.ndarray, Jinv: jnp.ndarray) -> jnp.ndarray:
+    """``f [10, 3, E]`` = Σ_p B_pᵀ s_p for weighted Voigt stresses
+    ``s [P, 6, E]``: f[n,i] = Σ_p Σ_j σ_ij(p) ∂N_n/∂x_j."""
+    g = assembly.lane_major_gradients(Jinv)                     # [P,3,10,E]
+    V = np.asarray(assembly.VOIGT)
+    st = s[:, V]                                                # [P,3,3,E] σ_ij
+    f = (st[:, :, :, None, :] * g[:, None]).sum(axis=(0, 2))    # [3,10,E]
+    return jnp.swapaxes(f, 0, 1)
 
 
-def ebe_element_matvec(
-    u_e: jnp.ndarray,    # [E,10,3]
+def ebe_element_lanes(
+    uT: jnp.ndarray,     # [30,E] nodal displacements, row 3n+a
     D: jnp.ndarray,      # [E,P,6,6] tangent at Gauss points
-    Jinv: jnp.ndarray,
-    wdet: jnp.ndarray,
+    Jinv: jnp.ndarray,   # [E,3,3]
+    wdet: jnp.ndarray,   # [E,P]
     coef_e: jnp.ndarray | None = None,  # [E] per-element scale (e.g. 1+2β_e/dt)
 ) -> jnp.ndarray:
-    """K_e u_e without forming K_e: ε → Dε → Bᵀ, fused (the EBE product)."""
-    eps = elem_strain(u_e, Jinv)                       # [E,P,6]
-    sig = jnp.einsum("epab,epb->epa", D, eps)          # [E,P,6]
-    w = wdet if coef_e is None else wdet * coef_e[:, None]
-    return elem_internal_force(sig, Jinv, w)
+    """K_e u_e without forming K_e: ε → Dε → Bᵀ, fused (the EBE product),
+    in the element kernel's lane-major calling convention (→ ``[30,E]``)."""
+    E, P = D.shape[:2]
+    eps = strain_lanes(uT.reshape(quad.NNODE, 3, E), Jinv)      # [P,6,E]
+    Dt = jnp.moveaxis(D, 0, -1)                                 # [P,6,6,E]
+    sig = (Dt * eps[:, None]).sum(axis=2)                       # [P,6,E]
+    w = wdet.T if coef_e is None else wdet.T * coef_e[None]
+    return force_lanes(sig * w[:, None], Jinv).reshape(quad.NDOF, E)
 
 
 def ebe_matvec(
@@ -120,28 +135,75 @@ def ebe_matvec(
 ) -> jnp.ndarray:
     """Full matrix-free K·x (gather → element product → sorted scatter).
 
-    ``element_kernel`` lets the Pallas kernel replace the jnp contraction.
+    ``element_kernel`` (lane-major: ``[30,E]`` in and out, the
+    ``ebe_element_lanes`` convention) lets the Pallas kernel replace the jnp
+    contraction.
     """
-    u_e = gather_elem(x, mesh.conn)
-    kern = element_kernel or ebe_element_matvec
-    f_e = kern(u_e, D, jnp.asarray(mesh.Jinv, x.dtype), jnp.asarray(mesh.wdet, x.dtype), coef_e)
-    return scatter_add(f_e, mesh.scatter_perm, mesh.scatter_segids, mesh.ndof)
-
-
-def strain_at_points(u: jnp.ndarray, mesh) -> jnp.ndarray:
-    """Total strain at all evaluation points ``[E*P, 6]`` (multispring input)."""
-    u_e = gather_elem(u, mesh.conn)
-    eps = elem_strain(u_e, jnp.asarray(mesh.Jinv, u.dtype))
-    E, P = eps.shape[:2]
-    return eps.reshape(E * P, 6)
-
-
-def internal_force(sigma_pts: jnp.ndarray, mesh) -> jnp.ndarray:
-    """Assembled internal force q ``[N,3]`` from point stresses ``[E*P,6]``."""
-    P = quad.NPOINT
     E = mesh.n_elem
-    sig = sigma_pts.reshape(E, P, 6)
-    f_e = elem_internal_force(
-        sig, jnp.asarray(mesh.Jinv, sigma_pts.dtype), jnp.asarray(mesh.wdet, sigma_pts.dtype)
+    uT = gather_nodal(x, mesh).reshape(quad.NDOF, E)
+    kern = element_kernel or ebe_element_lanes
+    fT = kern(uT, D, jnp.asarray(mesh.Jinv, x.dtype), jnp.asarray(mesh.wdet, x.dtype), coef_e)
+    return scatter_nodal(fT.reshape(quad.NNODE, 3, E), mesh)
+
+
+# ---------------------------------------------------------------------------
+# per-evaluation-point path: strain in, stress out (the multispring interface)
+# ---------------------------------------------------------------------------
+#
+# The multispring update works on evaluation points ``q = e*P + p``.  Moving
+# between ``[P, …, E]`` element layouts and that point order interleaves P
+# into the minor axis — a relayout whose XLA code compiles for ~100 s at
+# 48,000 elements.  So strain and internal force are computed per point,
+# from tables built once per mesh on the host.
+
+
+class PointTables(NamedTuple):
+    """Per-point gather/scatter maps and shape-function gradients, point
+    index ``q = e*P + p`` on the minor axis."""
+
+    nodes: jnp.ndarray    # [10, E*P] node of slot n of q's element
+    grads: jnp.ndarray    # [3, 10, E*P] ∂N_n/∂x_j at q
+    wdet: jnp.ndarray     # [E*P] quadrature weight × |J|
+    perm: jnp.ndarray     # [10*E*P] argsort of nodes.ravel()
+    segids: jnp.ndarray   # [10*E*P] sorted node ids
+    n_nodes: int
+
+
+def point_tables(mesh, dtype) -> PointTables:
+    nodes = np.repeat(mesh.conn, quad.NPOINT, axis=0).T           # [10, E*P]
+    g = quad.physical_gradients(np.asarray(mesh.Jinv))            # [E,P,10,3]
+    grads = g.transpose(3, 2, 0, 1).reshape(3, quad.NNODE, -1)
+    flat = nodes.reshape(-1)
+    perm = np.argsort(flat, kind="stable")
+    return PointTables(
+        nodes=jnp.asarray(nodes, jnp.int32),
+        grads=jnp.asarray(grads, dtype),
+        wdet=jnp.asarray(np.asarray(mesh.wdet).reshape(-1), dtype),
+        perm=jnp.asarray(perm, jnp.int32),
+        segids=jnp.asarray(flat[perm], jnp.int32),
+        n_nodes=mesh.n_nodes,
     )
-    return scatter_add(f_e, mesh.scatter_perm, mesh.scatter_segids, mesh.ndof)
+
+
+def strain_at_points(u: jnp.ndarray, pts: PointTables) -> jnp.ndarray:
+    """Total strain at all evaluation points ``[E*P, 6]`` (multispring
+    input); engineering shear, as the B-matrices."""
+    g = pts.grads.astype(u.dtype)
+    uq = [u[:, a][pts.nodes] for a in range(3)]                   # 3 × [10,E*P]
+    H = [[(uq[i] * g[j]).sum(axis=0) for j in range(3)] for i in range(3)]
+    return jnp.stack([H[0][0], H[1][1], H[2][2],
+                      H[0][1] + H[1][0], H[1][2] + H[2][1], H[2][0] + H[0][2]]).T
+
+
+def internal_force(sigma_pts: jnp.ndarray, pts: PointTables) -> jnp.ndarray:
+    """Assembled internal force q ``[N,3]`` from point stresses ``[E*P,6]``:
+    q[node, i] = Σ_q Σ_j σ_ij(q) wdet(q) ∂N/∂x_j, sorted segment-sums."""
+    g = pts.grads.astype(sigma_pts.dtype)
+    s = sigma_pts.T * pts.wdet.astype(sigma_pts.dtype)[None]      # [6,E*P]
+    V = assembly.VOIGT
+    return jnp.stack([
+        jax.ops.segment_sum(
+            sum(s[V[a][j]][None] * g[j] for j in range(3)).reshape(-1)[pts.perm],
+            pts.segids, num_segments=pts.n_nodes, indices_are_sorted=True)
+        for a in range(3)
+    ], axis=1)

@@ -13,7 +13,8 @@ TPU adaptation of the paper's CUDA EBE kernel (DESIGN.md §8):
   trade FLOPs for memory traffic and capacity.
 
 Data layout is struct-of-arrays with E innermost (``[k, E]``) so each block
-is a ``[k, TILE_E]`` VMEM tile with E on lanes; ops.py does the transposes.
+is a ``[k, TILE_E]`` VMEM tile with E on lanes; the nodal operands arrive
+that way from ``fem.spmv``'s lane-major gather.
 
 VMEM budget per block (TILE_E=512, fp32):
   u 30·512·4 = 60 KB, Jinv 9·512·4 = 18 KB, D 4·36·512·4 = 288 KB,
@@ -96,8 +97,8 @@ def _ebe_kernel(u_ref, jinv_ref, wdet_ref, d_ref, coef_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile_e", "interpret"))
-def ebe_element_matvec_pallas(
-    u_e: jnp.ndarray,    # [E,10,3]
+def ebe_element_lanes_pallas(
+    uT: jnp.ndarray,     # [30,E] nodal displacements, row 3n+a
     D: jnp.ndarray,      # [E,P,6,6]
     Jinv: jnp.ndarray,   # [E,3,3]
     wdet: jnp.ndarray,   # [E,P]
@@ -106,27 +107,41 @@ def ebe_element_matvec_pallas(
     tile_e: int = 512,
     interpret: bool = True,  # CPU container: interpret; TPU: False
 ) -> jnp.ndarray:
-    E = u_e.shape[0]
-    dt = u_e.dtype
+    """The element product in the lane-major convention of
+    ``fem.spmv.ebe_element_lanes``: ``[30,E]`` in, ``[30,E]`` out.  The
+    last block of an ``E`` that ``tile_e`` does not divide is ragged (its
+    out-of-range lanes are never written back)."""
+    E = uT.shape[1]
+    dt = uT.dtype
     if coef is None:
         coef = jnp.ones((E,), dt)
-    Epad = -(-E // tile_e) * tile_e
-    pad = Epad - E
-
-    uT = jnp.pad(u_e.reshape(E, 30), ((0, pad), (0, 0))).T          # [30,Ep]
-    jT = jnp.pad(Jinv.reshape(E, 9), ((0, pad), (0, 0))).T          # [9,Ep]
-    wT = jnp.pad(wdet, ((0, pad), (0, 0))).T                        # [P,Ep]
-    dT = jnp.pad(D.reshape(E, NPOINT * 36), ((0, pad), (0, 0))).T   # [P*36,Ep]
-    cT = jnp.pad(coef.astype(dt)[None, :], ((0, 0), (0, pad)))      # [1,Ep]
-
-    grid = (Epad // tile_e,)
+    jT = Jinv.astype(dt).reshape(E, 9).T                 # [9,E]
+    wT = wdet.astype(dt).T                               # [P,E]
+    dT = D.astype(dt).reshape(E, NPOINT * 36).T          # [P*36,E]
+    cT = coef.astype(dt)[None, :]                        # [1,E]
     spec = lambda rows: pl.BlockSpec((rows, tile_e), lambda i: (0, i))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _ebe_kernel,
-        grid=grid,
+        grid=(pl.cdiv(E, tile_e),),
         in_specs=[spec(30), spec(9), spec(NPOINT), spec(NPOINT * 36), spec(1)],
         out_specs=spec(30),
-        out_shape=jax.ShapeDtypeStruct((30, Epad), dt),
+        out_shape=jax.ShapeDtypeStruct((30, E), dt),
         interpret=interpret,
     )(uT, jT, wT, dT, cT)
-    return out.T[:E].reshape(E, NNODE, 3)
+
+
+def ebe_element_matvec_pallas(
+    u_e: jnp.ndarray,    # [E,10,3]
+    D: jnp.ndarray,      # [E,P,6,6]
+    Jinv: jnp.ndarray,   # [E,3,3]
+    wdet: jnp.ndarray,   # [E,P]
+    coef: jnp.ndarray | None = None,  # [E]
+    *,
+    tile_e: int = 512,
+    interpret: bool = True,
+) -> jnp.ndarray:
+    """Element-major form ``[E,10,3]`` → ``[E,10,3]`` (tests, benchmarks)."""
+    E = u_e.shape[0]
+    fT = ebe_element_lanes_pallas(u_e.reshape(E, 30).T, D, Jinv, wdet, coef,
+                                  tile_e=tile_e, interpret=interpret)
+    return fT.T.reshape(E, NNODE, 3)

@@ -1,6 +1,6 @@
 """Pure-jnp oracle for the EBE element product (no Pallas).
 
-Identical math to fem/spmv.ebe_element_matvec, restated here so the kernel
+Identical math to fem/spmv.ebe_element_lanes (element-major here), restated here so the kernel
 package is self-contained: f_e = Σ_p wdet_p·coef_e · B_pᵀ D_p B_p u_e with
 B built on the fly from the constant element Jacobian.
 """

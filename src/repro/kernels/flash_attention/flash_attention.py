@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams → CompilerParams across pallas versions
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _NEG = -1e30
 
 
@@ -134,7 +131,7 @@ def flash_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, sq_pad, dv_pad), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

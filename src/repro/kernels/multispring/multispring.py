@@ -2,16 +2,18 @@
 
 TPU adaptation of the paper's CUDA multi-spring kernel (DESIGN.md §2):
 
-* **springs on the 128-lane axis, evaluation points on sublanes** — a block
-  is ``[TILE_P, S_pad]`` with S padded to a lane multiple by zero-*weight*
-  springs (they compute but contribute nothing);
+* **evaluation points on the 128-lane axis, springs on sublanes** — a block
+  is ``[S, TILE_P]``.  At the paper's 150 springs this pads S to 152
+  sublanes, where springs on lanes would pad to 256.  The wrapper
+  transposes the ``[P, S]`` state; whether XLA then stores it as
+  ``[S, P]`` in HBM, so that the transposes cost no copy, is not verified;
 * the per-spring Masing branch logic (SIMT divergent threads on the GPU)
   becomes **lane predication** (`jnp.where`), which is exactly how the VPU
   executes divergent element-wise control flow;
-* the two reductions over springs — σ = (w·τ)ᵀn and the tangent assembly
-  D = Σ w·G_tan·(n⊗n) — are ``[TILE_P,S] @ [S,6]`` and ``[TILE_P,S] @ [S,36]``
-  matmuls: they land on the **MXU**, which the scalar-per-thread GPU
-  formulation cannot do.  This is the kernel's main TPU-native win.
+* the two reductions over springs — σ = nᵀ(w·τ) and the tangent assembly
+  D = Σ w·G_tan·(n⊗n) — are ``[6,S] @ [S,TILE_P]`` and
+  ``[36,S] @ [S,TILE_P]`` matmuls: they land on the **MXU**, which the
+  scalar-per-thread GPU formulation cannot do.
 
 Each grid step processes TILE_P evaluation points; the full spring state for
 those points streams HBM→VMEM→HBM once — the kernel is the compute stage of
@@ -26,28 +28,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _ms_kernel(
     eps_ref, grev_ref, trev_ref, gprev_ref, gmax_ref, dir_ref, virg_ref,
-    g0_ref, gr_ref, be_ref, bulk_ref, n_ref, nt_ref, nn_ref, w_ref,
+    g0_ref, gr_ref, be_ref, bulk_ref, n_ref, nt_ref, nnt_ref, w_ref,
     # outputs
     sig_ref, d_ref, frac_ref,
     ngrev_ref, ntrev_ref, ngprev_ref, ngmax_ref, ndir_ref, nvirg_ref,
 ):
-    """One TILE_P block of evaluation points.
+    """One TILE_P block of evaluation points (points on lanes).
 
-    eps [T,6] · state [T,S] · params [T,1] · n [S,6] (+ nᵀ [6,S], nn [S,36],
-    w [1,S]) → σ [T,6], D [T,36], frac [T,1], new state [T,S].
+    eps [6,T] · state [S,T] · params [1,T] · n [S,6] (+ nᵀ [6,S],
+    (n⊗n)ᵀ [36,S], w [S,1]) → σ [6,T], D [36,T], frac [1,T], new state [S,T].
     """
     eps = eps_ref[...]
-    G0 = g0_ref[...]       # [T,1]
+    G0 = g0_ref[...]       # [1,T]
     gr = gr_ref[...]
     be = be_ref[...]
     bulk = bulk_ref[...]
     n = n_ref[...]         # [S,6]
     nT = nt_ref[...]       # [6,S]
-    nn = nn_ref[...]       # [S,36]
-    w = w_ref[...]         # [1,S]
+    nnT = nnt_ref[...]     # [36,S]
+    w = w_ref[...]         # [S,1]
+    dot = functools.partial(jnp.dot, preferred_element_type=eps.dtype, precision=_HIGHEST)
 
     def backbone(g):
         x = jnp.abs(g) / gr
@@ -58,7 +63,7 @@ def _ms_kernel(
         den = 1.0 + x**be
         return G0 * (1.0 + (1.0 - be) * x**be) / (den * den)
 
-    gamma = jnp.dot(eps, nT, preferred_element_type=eps.dtype)  # [T,S] MXU
+    gamma = dot(n, eps)  # [S,T] MXU
     g_prev = gprev_ref[...]
     dgam = gamma - g_prev
     moving = jnp.sign(dgam).astype(jnp.int32)
@@ -86,24 +91,22 @@ def _ms_kernel(
     g_tan = jnp.where(on_bb, backbone_tan(gamma), backbone_tan(0.5 * (gamma - gamma_rev)))
     g_tan = jnp.maximum(g_tan, 1e-3 * G0)
 
-    tw = tau * w                                  # [T,S]
-    gw = g_tan * w
-    sigma_dev = jnp.dot(tw, n, preferred_element_type=eps.dtype)   # [T,6] MXU
-    D_dev = jnp.dot(gw, nn, preferred_element_type=eps.dtype)      # [T,36] MXU
+    sigma_dev = dot(nT, tau * w)    # [6,T] MXU
+    D_dev = dot(nnT, g_tan * w)     # [36,T] MXU
 
-    vol = eps[:, 0:1] + eps[:, 1:2] + eps[:, 2:3]  # [T,1]
+    vol = eps[0:1] + eps[1:2] + eps[2:3]  # [1,T]
     # volumetric masks built from iota (kernels may not capture constants)
-    i6 = jax.lax.iota(jnp.int32, 6)
+    i6 = jax.lax.broadcasted_iota(jnp.int32, (6, 1), 0)
     one6 = (i6 < 3).astype(eps.dtype)
-    sig_ref[...] = sigma_dev + bulk * vol * one6[None, :]
-    i36 = jax.lax.iota(jnp.int32, 36)
+    sig_ref[...] = sigma_dev + bulk * vol * one6
+    i36 = jax.lax.broadcasted_iota(jnp.int32, (36, 1), 0)
     one36 = (((i36 // 6) < 3) & ((i36 % 6) < 3)).astype(eps.dtype)
-    d_ref[...] = D_dev + bulk * one36[None, :]
+    d_ref[...] = D_dev + bulk * one36
 
     # damping fraction: mean over springs of 1 − 1/(1+(γ_max/γr)^β)
     x = (gamma_max / gr) ** be
-    wsum = jnp.maximum(jnp.sum(jnp.sign(jnp.abs(w))), 1.0)  # count real springs
-    frac = jnp.sum(jnp.where(w > 0, 1.0 - 1.0 / (1.0 + x), 0.0), axis=1, keepdims=True) / wsum
+    wsum = jnp.maximum(jnp.sum(jnp.sign(jnp.abs(w))), 1.0)  # count weighted springs
+    frac = jnp.sum(jnp.where(w > 0, 1.0 - 1.0 / (1.0 + x), 0.0), axis=0, keepdims=True) / wsum
     frac_ref[...] = frac
 
     ngrev_ref[...] = gamma_rev
@@ -125,78 +128,53 @@ def multispring_pallas(
     tile_p: int = 256,
     interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray, dict[str, jnp.ndarray], jnp.ndarray]:
-    """Returns (σ [P,6], D [P,6,6], new_state, frac [P]) — kernel layout/pad
-    handled here: S → lane multiple via zero-weight springs, P → tile_p."""
+    """Returns (σ [P,6], D [P,6,6], new_state, frac [P]).
+
+    The kernel sees every ``[P, …]`` array transposed (points on lanes).  A
+    block spans all ``S`` springs, and the last block of a ``P`` that
+    ``tile_p`` does not divide is ragged (its out-of-range lanes are never
+    written back), so no padded copy of the state is made in HBM."""
     P, S = state["gamma_rev"].shape
     dt = eps.dtype
-    S_pad = max(128, -(-S // 128) * 128)
-    P_pad = -(-P // tile_p) * tile_p
-
-    def padP(x, c=0):
-        return jnp.pad(x, ((0, P_pad - P),) + ((0, 0),) * (x.ndim - 1), constant_values=c)
-
-    def padS(x):
-        return jnp.pad(x, ((0, P_pad - P), (0, S_pad - S)))
-
-    n_p = jnp.pad(n.astype(dt), ((0, S_pad - S), (0, 0)))
-    w_p = jnp.pad(w.astype(dt), (0, S_pad - S))[None, :]       # zero-weight pad springs
-    nn = (n_p[:, :, None] * n_p[:, None, :]).reshape(S_pad, 36)
-
-    col = lambda a: padP(a.astype(dt)[:, None], 1)  # pad params with 1 (avoid /0)
+    n = n.astype(dt)
+    nnT = (n[:, :, None] * n[:, None, :]).reshape(S, 36).T
+    row = lambda a: a.astype(dt)[None, :]
+    keys = ("gamma_rev", "tau_rev", "gamma_prev", "gamma_max", "direction", "virgin")
+    stT = [state[k].astype(dt if k not in ("direction", "virgin") else jnp.int32).T
+           for k in keys]
     args = [
-        padP(eps.astype(dt)),
-        padS(state["gamma_rev"].astype(dt)),
-        padS(state["tau_rev"].astype(dt)),
-        padS(state["gamma_prev"].astype(dt)),
-        padS(state["gamma_max"].astype(dt)),
-        padS(state["direction"]),
-        padS(state["virgin"]),
-        col(params.G0),
-        col(params.gamma_r),
-        col(params.beta),
-        col(params.bulk),
-        n_p,
-        n_p.T,
-        nn,
-        w_p,
+        eps.astype(dt).T,
+        *stT,
+        row(params.G0),
+        row(params.gamma_r),
+        row(params.beta),
+        row(params.bulk),
+        n,
+        n.T,
+        nnT,
+        w.astype(dt)[:, None],
     ]
-    grid = (P_pad // tile_p,)
-    rowspec = lambda c: pl.BlockSpec((tile_p, c), lambda i: (i, 0))
-    statespec = pl.BlockSpec((tile_p, S_pad), lambda i: (i, 0))
+    grid = (pl.cdiv(P, tile_p),)
+    colspec = lambda r: pl.BlockSpec((r, tile_p), lambda i: (0, i))
     fullspec = lambda r, c: pl.BlockSpec((r, c), lambda i: (0, 0))
     in_specs = [
-        rowspec(6),
-        statespec, statespec, statespec, statespec, statespec, statespec,
-        rowspec(1), rowspec(1), rowspec(1), rowspec(1),
-        fullspec(S_pad, 6), fullspec(6, S_pad), fullspec(S_pad, 36), fullspec(1, S_pad),
+        colspec(6),
+        *[colspec(S)] * 6,
+        colspec(1), colspec(1), colspec(1), colspec(1),
+        fullspec(S, 6), fullspec(6, S), fullspec(36, S), fullspec(S, 1),
     ]
-    out_specs = [
-        rowspec(6), rowspec(36), rowspec(1),
-        statespec, statespec, statespec, statespec, statespec, statespec,
-    ]
+    out_specs = [colspec(6), colspec(36), colspec(1), *[colspec(S)] * 6]
     out_shape = [
-        jax.ShapeDtypeStruct((P_pad, 6), dt),
-        jax.ShapeDtypeStruct((P_pad, 36), dt),
-        jax.ShapeDtypeStruct((P_pad, 1), dt),
-        jax.ShapeDtypeStruct((P_pad, S_pad), dt),
-        jax.ShapeDtypeStruct((P_pad, S_pad), dt),
-        jax.ShapeDtypeStruct((P_pad, S_pad), dt),
-        jax.ShapeDtypeStruct((P_pad, S_pad), dt),
-        jax.ShapeDtypeStruct((P_pad, S_pad), jnp.int32),
-        jax.ShapeDtypeStruct((P_pad, S_pad), jnp.int32),
+        jax.ShapeDtypeStruct((6, P), dt),
+        jax.ShapeDtypeStruct((36, P), dt),
+        jax.ShapeDtypeStruct((1, P), dt),
+        *[jax.ShapeDtypeStruct((S, P), dt)] * 4,
+        *[jax.ShapeDtypeStruct((S, P), jnp.int32)] * 2,
     ]
     outs = pl.pallas_call(
         _ms_kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
     )(*args)
-    sig, Dflat, frac, grev, trev, gprev, gmax, dire, virg = outs
-    unS = lambda a: a[:P, :S]
-    new_state = {
-        "gamma_rev": unS(grev),
-        "tau_rev": unS(trev),
-        "gamma_prev": unS(gprev),
-        "gamma_max": unS(gmax),
-        "direction": unS(dire),
-        "virgin": unS(virg),
-    }
-    return sig[:P], Dflat[:P].reshape(P, 6, 6), new_state, frac[:P, 0]
+    sig, Dflat, frac, *new = outs
+    new_state = {k: a.T for k, a in zip(keys, new)}
+    return sig.T, Dflat.T.reshape(P, 6, 6), new_state, frac[0]

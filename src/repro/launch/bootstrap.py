@@ -29,6 +29,26 @@ import dataclasses
 import os
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/launch/bootstrap.py → the checkout root
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return its directory: the one ``JAX_COMPILATION_CACHE_DIR`` names when
+    it is set (JAX reads the variable itself; no other directory is set),
+    else a fixed ``.jax_cache/`` at the root of the checkout — fixed,
+    because the path is part of what the cache matches on.  Entry points
+    call this; nothing does at import time."""
+    if os.environ.get(_CACHE_ENV):
+        return os.environ[_CACHE_ENV]
+    import jax  # noqa: PLC0415 (deliberate lazy import, see module docstring)
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def force_host_devices(flag: str = "--host-devices", default: int = 0) -> int:

@@ -58,14 +58,18 @@ Flags
     its checkpoint by any survivor.  ``--worker-id NAME`` instead joins the
     queue as a single in-process worker (launch as many as you like,
     whenever you like); ``--max-jobs`` caps how many groups such a worker
-    takes before leaving.
+    takes before leaving.  The pool parent never opens a JAX backend
+    before its workers start, and every worker opens all visible
+    accelerators: when a short-lived probe child finds an accelerator, the
+    parent refuses ``--workers`` > 1 and ``--train-while-generating``.  A
+    sweep whose group failed exits non-zero.
 ``--train-while-generating [--train-steps N]``
     Overlap surrogate training with generation: the parent streams
     committed scenario shards out of ``--out`` in plan order
     (``ShardStream``) and runs ``fit_stream`` while the workers are still
     producing — deterministic batches regardless of worker count or shard
     arrival, so the result equals a post-hoc ``fit_shards`` on the
-    finished dataset.
+    finished dataset.  A training failure fails the launch.
 ``--autotune / --probe``
     Pick ``(method, npart, kset)`` per plan group with the cost model
     (``--autotune``); ``--probe`` additionally times the shortlisted
@@ -243,6 +247,16 @@ def main(argv=None):
     ap.add_argument("--cpu-backend", action="store_true",
                     help="force the CPU backend (multi-process rehearsal)")
     args = ap.parse_args(argv)
+    scenario_mode = bool(args.sweep or args.scenario or args.scenarios)
+    if args.schedule and scenario_mode and not args.worker_id:
+        # the pool parent plans, spawns and watches; its workers hold the
+        # devices, so it must not initialize a JAX backend before they start
+        if args.num_processes > 1:
+            raise SystemExit("[campaign] --schedule pools are single-process; "
+                             "drop the distributed flags")
+        _refuse_scenario_only_flags(args, "[campaign]")
+        plan = _make_plan(args, "[campaign]")
+        return _run_pool(args, "[campaign]", plan)
 
     from repro.launch.bootstrap import DistributedArgs, distributed_init
 
@@ -270,14 +284,8 @@ def main(argv=None):
 
     if args.trajectories and args.obs_every < 1:
         raise SystemExit(f"{tag} --obs-every must be ≥ 1, got {args.obs_every}")
-    if args.sweep or args.scenario or args.scenarios:
-        if args.trajectories:
-            raise SystemExit(f"{tag} --trajectories rides the plain campaign "
-                             f"path; drop --scenario/--sweep/--scenarios")
-        if args.inject:
-            raise SystemExit(f"{tag} --inject rides the plain campaign path "
-                             f"(scenario sweeps generate their own waves); "
-                             f"drop --scenario/--sweep/--scenarios")
+    if scenario_mode:
+        _refuse_scenario_only_flags(args, tag)
         return _run_scenarios(args, tag, np_, dmesh)
 
     cfg = EnsembleConfig(
@@ -380,20 +388,26 @@ def _sim_knobs(args) -> dict:
     )
 
 
-def _run_scenarios(args, tag, np_, dmesh) -> int:
-    """--scenario / --sweep: plan + run compile-grouped scenario campaigns."""
+def _refuse_scenario_only_flags(args, tag) -> None:
+    if args.trajectories:
+        raise SystemExit(f"{tag} --trajectories rides the plain campaign "
+                         f"path; drop --scenario/--sweep/--scenarios")
+    if args.inject:
+        raise SystemExit(f"{tag} --inject rides the plain campaign path "
+                         f"(scenario sweeps generate their own waves); "
+                         f"drop --scenario/--sweep/--scenarios")
+    if sum(map(bool, (args.sweep, args.scenario, args.scenarios))) > 1:
+        raise SystemExit(
+            f"{tag} pass one of --scenario / --sweep / --scenarios")
+
+
+def _make_plan(args, tag):
+    """--scenario / --sweep / --scenarios → the compile-grouped plan (host
+    work only: no JAX backend is touched)."""
     import dataclasses
 
     from repro import scenario as sc
 
-    if np_ > 1:
-        raise SystemExit(
-            f"{tag} --scenario/--sweep are single-process for now (multi-host "
-            f"campaigns take the plain flag path); drop the distributed flags"
-        )
-    if sum(map(bool, (args.sweep, args.scenario, args.scenarios))) > 1:
-        raise SystemExit(
-            f"{tag} pass one of --scenario / --sweep / --scenarios")
     if args.scenarios:
         from repro.serving.feedback import feedback_plan
 
@@ -408,19 +422,32 @@ def _run_scenarios(args, tag, np_, dmesh) -> int:
             nspring=args.nspring,
         )
         plan = sc.make_plan([scn])
+    print(f"{tag} plan: {plan.n_scenarios} scenario(s) in {len(plan.groups)} "
+          f"compile group(s), {plan.n_cases} case(s)"
+          + (" [autotune]" if args.autotune else f" method={args.method}"))
+    return plan
+
+
+def _run_scenarios(args, tag, np_, dmesh) -> int:
+    """--scenario / --sweep: plan + run compile-grouped scenario campaigns."""
+    from repro import scenario as sc
+
+    if np_ > 1:
+        raise SystemExit(
+            f"{tag} --scenario/--sweep are single-process for now (multi-host "
+            f"campaigns take the plain flag path); drop the distributed flags"
+        )
+    plan = _make_plan(args, tag)
     from repro.fem import backend as fem_backend
 
     kb = fem_backend.resolve(backend=args.kernel_backend,
                              ebe=args.ebe_backend or None,
                              multispring=args.ms_backend or None,
                              tile_e=args.tile_e, tile_p=args.tile_p)
-    print(f"{tag} plan: {plan.n_scenarios} scenario(s) in {len(plan.groups)} "
-          f"compile group(s), {plan.n_cases} case(s)"
-          + (" [autotune]" if args.autotune else f" method={args.method}"))
     print(f"{tag} kernel backend: {kb.describe()} "
           f"warm_start={args.warm_start} precond_every={args.precond_every}")
     if args.schedule:
-        return _run_scheduled(args, tag, plan, dmesh)
+        return _run_worker(args, tag, plan, dmesh)
     run = sc.run_plan(
         plan, autotune=args.autotune, probe=args.probe,
         method=args.method, kset=args.kset, health=args.health,
@@ -430,10 +457,16 @@ def _run_scenarios(args, tag, np_, dmesh) -> int:
         stop_after_steps=args.stop_after_steps,
         log=lambda m: print(f"{tag} {m}"),
     )
+    failed = {k: st["error"] for k, st in run.group_stats.items()
+              if st.get("failed")}
+    for key, err in failed.items():
+        print(f"{tag} [failed] group {key[:8]}: {err}")
     if len(run.scenarios) < plan.n_scenarios:
         print(f"{tag} [stopped] {len(run.scenarios)}/{plan.n_scenarios} "
-              f"scenario(s) finished — relaunch to resume")
-        return 0
+              f"scenario(s) finished"
+              + (f", {len(failed)} group(s) failed" if failed
+                 else " — relaunch to resume"))
+        return 1 if failed else 0
     for name, sr in run.scenarios.items():
         peak = float(np.abs(sr.responses).max()) if sr.responses.size else 0.0
         print(f"{tag} [done] {name}: {len(sr.waves)} case(s), "
@@ -489,38 +522,92 @@ def _worker_cmd(args, worker: str) -> list:
     return cmd
 
 
-def _run_scheduled(args, tag, plan, dmesh) -> int:
-    """--schedule: the elastic queue path (worker child, or parent pool)."""
+def _queue_config(args, tag):
+    from repro.scenario import scheduler as sched
+
+    if not (args.ckpt_dir or args.out):
+        raise SystemExit(f"{tag} --schedule needs --ckpt-dir or --out to "
+                         f"host the on-disk queue")
+    return sched.SchedulerConfig(lease_s=args.lease_s)
+
+
+def _run_worker(args, tag, plan, dmesh) -> int:
+    """--schedule --worker-id: one in-process worker of the elastic queue
+    (a worker of a user-managed pool; the single worker of a pool launch
+    without ``--worker-id`` is spawned by :func:`_run_pool`)."""
+    from repro.scenario import scheduler as sched
+
+    cfg = _queue_config(args, tag)
+    s = sched.run_worker(
+        plan, worker=args.worker_id, scheduler=cfg, device_mesh=dmesh,
+        ckpt_dir=args.ckpt_dir, out_dir=args.out,
+        shard_size=args.shard_size, max_jobs=args.max_jobs,
+        stop_after_steps=args.stop_after_steps,
+        log=lambda m: print(f"{tag} {m}"), **_group_knobs(args),
+    )
+    print(f"{tag} [worker {s.worker}] done={len(s.done)} "
+          f"failed={len(s.failed)} preempted={len(s.preempted)} "
+          f"quarantined={len(s.quarantined)} settled={s.settled}"
+          + (f" DEAD groups: {s.dead}" if s.dead else ""))
+    return 1 if s.dead else 0
+
+
+_PROBE = "import jax; print(jax.default_backend())"
+
+
+def _child_platform(args) -> str:
+    """The JAX backend a spawned worker would open.  The parent must not
+    open one itself (it would then hold the chip its workers need), so a
+    short-lived probe child answers and exits before any worker starts."""
+    if args.cpu_backend or os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
+        return "cpu"
+    import subprocess
+
+    r = subprocess.run([sys.executable, "-c", _PROBE], capture_output=True,
+                       text=True, timeout=600)
+    lines = r.stdout.split()
+    if r.returncode or not lines:
+        raise SystemExit(f"JAX backend probe failed (rc={r.returncode}): "
+                         f"{r.stderr.strip()[-2000:]}")
+    return lines[-1]
+
+
+def _run_pool(args, tag, plan) -> int:
+    """--schedule without --worker-id: spawn and watch a worker pool.
+
+    The parent never initializes a JAX backend before its workers run: a
+    process that has touched the accelerator holds it, and a worker that
+    then needs it fails or hangs.  Every worker opens all visible devices,
+    so on an accelerator host only one worker may run, and the parent may
+    not train next to it."""
     import subprocess
     import threading
     import time as _time
 
     from repro.scenario import scheduler as sched
 
-    if not (args.ckpt_dir or args.out):
-        raise SystemExit(f"{tag} --schedule needs --ckpt-dir or --out to "
-                         f"host the on-disk queue")
-    cfg = sched.SchedulerConfig(lease_s=args.lease_s)
-
-    if args.worker_id:  # ---- I am one worker of a user-managed pool ----
-        s = sched.run_worker(
-            plan, worker=args.worker_id, scheduler=cfg, device_mesh=dmesh,
-            ckpt_dir=args.ckpt_dir, out_dir=args.out,
-            shard_size=args.shard_size, max_jobs=args.max_jobs,
-            stop_after_steps=args.stop_after_steps,
-            log=lambda m: print(f"{tag} {m}"), **_group_knobs(args),
-        )
-        print(f"{tag} [worker {s.worker}] done={len(s.done)} "
-              f"failed={len(s.failed)} preempted={len(s.preempted)} "
-              f"quarantined={len(s.quarantined)} settled={s.settled}"
-              + (f" DEAD groups: {s.dead}" if s.dead else ""))
-        return 1 if s.dead else 0
-
-    # ---- parent: spawn a monitored worker pool -----------------------------
+    cfg = _queue_config(args, tag)
     if args.train_while_generating and not args.out:
         raise SystemExit(f"{tag} --train-while-generating streams shards "
                          f"from --out; pass --out")
     n = max(1, args.workers)
+    env = None  # workers inherit this environment
+    if n > 1 or args.train_while_generating:
+        platform = _child_platform(args)
+        if platform != "cpu" and n > 1:
+            raise SystemExit(
+                f"{tag} --workers {n} on a {platform} host: every worker "
+                f"opens all visible accelerators, and a second process "
+                f"cannot hold a chip the first one holds.  Run --workers 1, "
+                f"join workers from other hosts with --worker-id, or run CPU "
+                f"workers with --cpu-backend (or JAX_PLATFORMS=cpu)")
+        if platform != "cpu":
+            raise SystemExit(
+                f"{tag} --train-while-generating on a {platform} host: the "
+                f"parent would open the chip its worker holds.  Train after "
+                f"the sweep (repro.surrogate.train.fit_shards on --out), or "
+                f"run with --cpu-backend (or JAX_PLATFORMS=cpu)")
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}  # what the probe found
     names = [f"w{i}" for i in range(n)]
     qdir = sched.queue_dir_for(args.ckpt_dir, args.out)
     os.makedirs(qdir, exist_ok=True)
@@ -531,7 +618,8 @@ def _run_scheduled(args, tag, plan, dmesh) -> int:
         lp = os.path.join(qdir, f"{w}.log")
         lf = open(lp, "w")
         procs.append(subprocess.Popen(
-            _worker_cmd(args, w), stdout=lf, stderr=subprocess.STDOUT))
+            _worker_cmd(args, w), stdout=lf, stderr=subprocess.STDOUT,
+            env=env))
         logs.append((lp, lf))
 
     trainer: dict = {}
@@ -580,7 +668,8 @@ def _run_scheduled(args, tag, plan, dmesh) -> int:
 
     q = sched.JobQueue(qdir, cfg)
     dead = [g.key for g in plan.groups if q.state(g.key) == "dead"]
-    ok = q.settled(plan) and not dead and not any(rcs)
+    ok = (q.settled(plan) and not dead and not any(rcs)
+          and "error" not in trainer)
     for w, rc, (lp, _) in zip(names, rcs, logs):
         if rc:
             print(f"{tag} [schedule] worker {w} exited rc={rc} — see {lp}")
@@ -592,4 +681,7 @@ def _run_scheduled(args, tag, plan, dmesh) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -15,15 +15,10 @@ import jax
 
 
 def make_auto_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the jax version has them.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older versions treat
-    every axis as Auto already, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -303,4 +303,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -107,4 +107,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.bootstrap import enable_compile_cache
+
+    enable_compile_cache()
     main()

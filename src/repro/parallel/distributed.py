@@ -14,10 +14,9 @@ coordination rides jax's distributed runtime service (the same service
 * a barrier between file writes must not require a device computation in
   the first place — it synchronizes *hosts*, not devices.
 
-``barrier()`` therefore prefers the coordination-service client and only
-falls back to ``multihost_utils.sync_global_devices`` (a device psum) if a
-future jax stops exposing the client.  Everything here degrades to a no-op
-in single-process runs, so callers never branch on world size.
+``barrier()`` therefore waits on the coordination-service client.
+Everything here degrades to a no-op in single-process runs, so callers
+never branch on world size.
 """
 from __future__ import annotations
 
@@ -46,15 +45,6 @@ def is_distributed() -> bool:
     return process_count() > 1
 
 
-def _coordination_client():
-    try:
-        from jax._src import distributed as _dist  # noqa: PLC0415
-        state = getattr(_dist, "global_state", None)
-        return getattr(state, "client", None)
-    except Exception:  # pragma: no cover - private-API drift on future jax
-        return None
-
-
 def barrier(tag: str, *, timeout_ms: int = _BARRIER_TIMEOUT_MS) -> None:
     """Block until every process reaches this barrier; no-op single-process.
 
@@ -65,14 +55,11 @@ def barrier(tag: str, *, timeout_ms: int = _BARRIER_TIMEOUT_MS) -> None:
     """
     if not is_distributed():
         return
-    seq = next(_counter)
-    client = _coordination_client()
-    if client is not None:
-        client.wait_at_barrier(f"{tag}_{seq}", timeout_ms)
-        return
-    from jax.experimental import multihost_utils  # pragma: no cover
+    # JAX exposes no public host barrier; its coordination-service client
+    # is the one that works on every backend (see the module docstring)
+    from jax._src import distributed as _dist  # noqa: PLC0415
 
-    multihost_utils.sync_global_devices(f"{tag}_{seq}")  # pragma: no cover
+    _dist.global_state.client.wait_at_barrier(f"{tag}_{next(_counter)}", timeout_ms)
 
 
 def free_port() -> int:

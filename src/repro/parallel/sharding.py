@@ -20,26 +20,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
-try:  # newer jax: public entry point, replication check renamed to check_vma
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # jax ≤ 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
 def shard_map(fn, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map`` (public on newer jax, experimental on
-    0.4.x, replication-check kwarg renamed between them).  The one entry
-    point for every SPMD region in the repo (gradient compression, campaign
-    case-sharding)."""
-    return _shard_map_impl(
-        fn,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: check},
+    """``jax.shard_map`` with the varying-axes check off by default.  The one
+    entry point for every SPMD region in the repo (gradient compression,
+    campaign case-sharding)."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 

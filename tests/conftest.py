@@ -108,13 +108,3 @@ try:  # pragma: no cover - exercised implicitly by collection
 except ModuleNotFoundError:  # bare container: install the deterministic shim
     _install_hypothesis_shim()
 
-
-# --- jax version compat ----------------------------------------------------
-# The suite targets newer jax where ``jax.enable_x64`` is a public context
-# manager; on older jax it lives in jax.experimental with identical behavior.
-import jax  # noqa: E402
-
-if not hasattr(jax, "enable_x64"):
-    from jax.experimental import enable_x64 as _enable_x64
-
-    jax.enable_x64 = _enable_x64

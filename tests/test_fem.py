@@ -71,8 +71,10 @@ def test_shape_functions_partition_of_unity():
 
 def test_matvec_equivalence(mesh, elastic, x64):
     _, D0, _ = elastic
-    K_e = assembly.element_stiffness(D0, jnp.asarray(mesh.Jinv), jnp.asarray(mesh.wdet))
-    vals = assembly.assemble_bcsr(K_e, mesh.entry_map, len(mesh.col_idx))
+    Jinv, wdet = jnp.asarray(mesh.Jinv), jnp.asarray(mesh.wdet)
+    K_e = assembly.element_stiffness(D0, Jinv, wdet)
+    vals = assembly.assemble_bcsr(
+        assembly.element_blocks(D0, Jinv, wdet), mesh.entry_map, len(mesh.col_idx))
     A = assembly.dense_assemble(K_e, mesh.elem_dofs, mesh.ndof)
     x = jax.random.normal(jax.random.key(0), (mesh.n_nodes, 3))
     y_dense = (A @ x.reshape(-1)).reshape(-1, 3)
@@ -103,8 +105,8 @@ def test_ebe_equals_crs_property(seed):
     Q = rng.normal(size=(m.n_elem, quad.NPOINT, 6, 6))
     D = jnp.asarray(Q @ Q.transpose(0, 1, 3, 2) + 6 * np.eye(6))
     x = jnp.asarray(rng.normal(size=(m.n_nodes, 3)))
-    K_e = assembly.element_stiffness(D, jnp.asarray(m.Jinv), jnp.asarray(m.wdet))
-    vals = assembly.assemble_bcsr(K_e, m.entry_map, len(m.col_idx))
+    Kb = assembly.element_blocks(D, jnp.asarray(m.Jinv), jnp.asarray(m.wdet))
+    vals = assembly.assemble_bcsr(Kb, m.entry_map, len(m.col_idx))
     y_crs = spmv.bcsr_matvec(vals, m.rowids, m.col_idx, x)
     y_ebe = spmv.ebe_matvec(x, D, m)
     np.testing.assert_allclose(
@@ -119,16 +121,15 @@ def test_ebe_equals_crs_property(seed):
 
 def _spd_system(mesh, elastic):
     _, D0, _ = elastic
-    K_e = assembly.element_stiffness(D0, jnp.asarray(mesh.Jinv), jnp.asarray(mesh.wdet))
-    vals = assembly.assemble_bcsr(K_e, mesh.entry_map, len(mesh.col_idx))
+    Kb = assembly.element_blocks(D0, jnp.asarray(mesh.Jinv), jnp.asarray(mesh.wdet))
+    vals = assembly.assemble_bcsr(Kb, mesh.entry_map, len(mesh.col_idx))
     diag_add = jnp.asarray(mesh.mass)[:, None] * 1e4  # mass term → SPD
     vals = assembly.add_diag(vals, mesh.diag_slots, diag_add)
     Minv = assembly.block_jacobi_inverse(vals, mesh.diag_slots)
 
     def mv(xf):  # dtype-follows-input (serves the fp32 inner solve too)
-        return spmv.bcsr_matvec(
-            vals.astype(xf.dtype), mesh.rowids, mesh.col_idx, xf.reshape(-1, 3)
-        ).reshape(-1)
+        return solver.flat(spmv.bcsr_matvec(
+            vals.astype(xf.dtype), mesh.rowids, mesh.col_idx, solver.unflat(xf)))
 
     return mv, Minv
 

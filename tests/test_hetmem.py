@@ -30,13 +30,23 @@ def _params(key, widths=(8, 16, 4, 32, 12)):
 
 
 def test_memory_kinds_present():
+    """Every backend of the installed JAX has device + pinned_host memory,
+    so Algorithm 3's placements are real copies (CPU included)."""
     kinds = hetmem.supported_memory_kinds()
-    assert kinds, "runtime must advertise at least one memory"
-    if hetmem.transfers_supported():  # TPU/GPU (or newer-jax CPU) runtimes
-        assert "device" in kinds
-        assert hetmem.host_memory_available(), kinds
-    else:  # single-memory runtime: placements are annotations (no-ops)
-        assert not hetmem.host_memory_available()
+    assert "device" in kinds and hetmem.HOST in kinds, kinds
+    assert hetmem.transfers_supported() and hetmem.host_memory_available()
+    assert hetmem.transfer_is_real(hetmem.HOST)
+    assert hetmem.transfer_is_real(hetmem.DEVICE)
+
+
+def test_transfer_to_a_missing_kind_raises():
+    """No silent fallback: a placement the runtime cannot make must fail,
+    not leave θ where it was."""
+    x = jnp.ones(4)
+    with pytest.raises(ValueError, match="cannot be a transfer target"):
+        hetmem.transfer(x, "no_such_memory")
+    with pytest.raises(ValueError, match="cannot be a transfer target"):
+        jax.jit(lambda a: hetmem.transfer(a, "unpinned_host"))(x)
 
 
 @given(npart=st.integers(1, 12), nleaf=st.integers(1, 9))
