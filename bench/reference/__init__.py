@@ -1,0 +1,1 @@
+"""Found by name from BENCHMARK.json (see bench/run.py)."""
