@@ -88,8 +88,7 @@ def main():
         if i % 10 == 0 or i == args.steps - 1:
             loss = float(metrics["nll"])
             tok_s = args.batch * args.seq / max(time.time() - t_step, 1e-9)
-            print(f"step {i:4d}  nll {loss:6.3f}  {tok_s/1e3:7.1f}k tok/s  "
-                  f"input-wait {it.last_wait_s*1e3:.0f}ms")
+            print(f"step {i:4d}  nll {loss:6.3f}  {tok_s/1e3:7.1f}k tok/s")
         wd.report(0, i, time.time() - t_step)
         if args.ckpt_every and i and i % args.ckpt_every == 0:
             mgr.save(i, {"params": params})

@@ -381,6 +381,11 @@ def run_campaign(
     its checkpoint — the fault-injection hook the kill-and-resume tests and
     the CI smoke use (a real SIGKILL anywhere is no worse: the previous
     checkpoint is atomic on disk).
+
+    Under ``jax.profiler.trace`` the host's part of the loop shows as
+    spans on the device's clock: ``repro.campaign.chunk`` (dispatch of a
+    chunk), ``repro.campaign.fetch`` (its ``device_get``),
+    ``repro.campaign.bank`` and ``repro.campaign.checkpoint`` (writes).
     """
     waves = np.asarray(waves)
     M, nt = waves.shape[0], waves.shape[1]
@@ -518,31 +523,37 @@ def run_campaign(
             if b <= t0:
                 continue  # already restored past this chunk
             a = max(a, t0)
-            carry, (vel, iters) = chunk_fn(carry, wave_r[:, a:b])
-            cur_vel.append(np.asarray(jax.device_get(vel)))
-            cur_iters.append(np.asarray(jax.device_get(iters)))
+            with jax.profiler.TraceAnnotation("repro.campaign.chunk"):
+                carry, (vel, iters) = chunk_fn(carry, wave_r[:, a:b])
+            with jax.profiler.TraceAnnotation("repro.campaign.fetch"):
+                cur_vel.append(np.asarray(jax.device_get(vel)))
+                cur_iters.append(np.asarray(jax.device_get(iters)))
             steps_done = r * nt + b
             if b == nt:  # round complete → bank it once, reset for the next
                 round_vel = np.concatenate(cur_vel, axis=1)
                 round_iters = np.concatenate(cur_iters, axis=1)
                 if guarded:  # final guarded carry = (inner, word, ncg)
-                    round_health = np.asarray(jax.device_get(carry[1]), np.int32)
-                    round_ncg = np.asarray(jax.device_get(carry[2]), np.int64)
+                    with jax.profiler.TraceAnnotation("repro.campaign.fetch"):
+                        round_health = np.asarray(jax.device_get(carry[1]), np.int32)
+                        round_ncg = np.asarray(jax.device_get(carry[2]), np.int64)
                 else:
                     round_health = round_ncg = None
                 done_rounds.append(
                     (round_vel, round_iters, round_health, round_ncg)
                 )
                 if mgr is not None:
-                    _bank_round(
-                        campaign.checkpoint_dir, r, round_vel, round_iters,
-                        topo, round_health, round_ncg,
-                    )
+                    with jax.profiler.TraceAnnotation("repro.campaign.bank"):
+                        _bank_round(
+                            campaign.checkpoint_dir, r, round_vel, round_iters,
+                            topo, round_health, round_ncg,
+                        )
                 cur_vel, cur_iters = [], []
                 completed = r + 1 == n_rounds
-                _save(r + 1, 0, carry0_b, blocking=completed)
+                with jax.profiler.TraceAnnotation("repro.campaign.checkpoint"):
+                    _save(r + 1, 0, carry0_b, blocking=completed)
             else:
-                _save(r, b, carry)
+                with jax.profiler.TraceAnnotation("repro.campaign.checkpoint"):
+                    _save(r, b, carry)
             if (
                 stop_after_steps is not None
                 and steps_done >= stop_after_steps
@@ -553,7 +564,8 @@ def run_campaign(
         if stopped or completed:
             break
     if mgr is not None:
-        mgr.wait()
+        with jax.profiler.TraceAnnotation("repro.campaign.checkpoint"):
+            mgr.wait()
 
     nr_done = len(done_rounds)
     # global waves row of each locally-held case, before masking out padding

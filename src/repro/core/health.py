@@ -130,18 +130,19 @@ def guard_step(step, *, springs_index: int = 1):
     def wrapped(hcarry, f_t):
         inner, word, ncg = hcarry
         new_inner, aux = step(inner, f_t)
-        live_before = is_live(word)
-        word_new = jnp.where(
-            live_before,
-            update_word(word, new_inner, new_inner[springs_index], aux),
-            word,
-        )
-        frozen = freeze(is_live(word_new), new_inner, inner)
-        # count genuine maxiter exhaustion only while the case is live
-        # (a non-finite residual trips BIT_SOLVER_NONFINITE instead)
-        ncg_new = ncg + jnp.where(
-            live_before & ~aux.converged & jnp.isfinite(aux.relres), 1, 0
-        ).astype(ncg.dtype)
+        with jax.named_scope("repro.health"):
+            live_before = is_live(word)
+            word_new = jnp.where(
+                live_before,
+                update_word(word, new_inner, new_inner[springs_index], aux),
+                word,
+            )
+            frozen = freeze(is_live(word_new), new_inner, inner)
+            # count genuine maxiter exhaustion only while the case is live
+            # (a non-finite residual trips BIT_SOLVER_NONFINITE instead)
+            ncg_new = ncg + jnp.where(
+                live_before & ~aux.converged & jnp.isfinite(aux.relres), 1, 0
+            ).astype(ncg.dtype)
         return (frozen, word_new, ncg_new), aux
 
     return wrapped

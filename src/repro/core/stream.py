@@ -247,9 +247,12 @@ class StreamEngine:
                 dev_blk, dev[j] = dev[j], None  # drop ref → bounded liveness
             else:
                 dev_blk = self._h2d(blocks[j])
-            args = tuple(pb[j] for pb in per_block)
-            out = call(dev_blk, carry, args, bc)
-            new_blk, carry, extra = self._unpack(out, has_carry, plan.collect)
+            # the block's compute in a named scope of its own, so a device
+            # trace attributes it to the block
+            with jax.named_scope(f"repro.stream_block{j}"):
+                args = tuple(pb[j] for pb in per_block)
+                out = call(dev_blk, carry, args, bc)
+                new_blk, carry, extra = self._unpack(out, has_carry, plan.collect)
             if plan.collect:
                 extras.append(extra)
             out_blocks.append(self._d2h(new_blk))

@@ -113,9 +113,11 @@ class FemOperators:
         self.kernel_backend = None
 
     # ---- constitutive -----------------------------------------------------
+    @jax.named_scope("repro.multispring")
     def multispring_all(self, eps_pts, spring_state):
         return self.multispring_fn(eps_pts, spring_state, self.params, self.n_dirs, self.w_dirs)
 
+    @jax.named_scope("repro.multispring")
     def multispring_block(self, blk, eps_blk, params_blk):
         """Per-streamed-block wrapper: blk is the spring-state leaf list.
 
@@ -152,6 +154,7 @@ class FemOperators:
         return out
 
     # ---- damping ----------------------------------------------------------
+    @jax.named_scope("repro.damping")
     def damping_from_frac(self, frac):
         """(α, β_e): Rayleigh from per-point damping fractions [E*P]."""
         h_pt = frac.reshape(self.mesh.n_elem, quad.NPOINT).mean(axis=1) * self.h_max
@@ -159,11 +162,13 @@ class FemOperators:
         alpha = 2.0 * jnp.mean(h_pt) * self.cfg.omega0
         return alpha, beta_e
 
+    @jax.named_scope("repro.damping")
     def damping_coeffs(self, spring_state):
         """(α, β_e) from a resident spring state."""
         return self.damping_from_frac(ms.hysteretic_damping(spring_state, self.params))
 
     # ---- operators ---------------------------------------------------------
+    @jax.named_scope("repro.crs_update")
     def crs_update(self, D, beta_e, alpha):
         """UpdateCRS: assemble A's BCSR values + block-Jacobi inverse."""
         Kb = assembly.element_blocks(D, self.Jinv, self.wdet)    # 9 × [10,10,E]
@@ -216,6 +221,7 @@ class FemOperators:
             return alpha * self.mass[:, None] * v + kv + self.dash * v
         return mv
 
+    @jax.named_scope("repro.ebe_diag")
     def ebe_diag_inverse(self, D, beta_e, alpha):
         """Block-Jacobi of A without assembling K (nodal diag blocks only),
         lane-major ``[9, N]`` like :func:`assembly.block_jacobi_inverse`.

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -30,6 +31,7 @@ def init_state(n_nodes: int, dtype=jnp.float64) -> NewmarkState:
     return NewmarkState(u=z, v=z, a=z, q=z)
 
 
+@jax.named_scope("repro.newmark")
 def rhs(
     state: NewmarkState,
     f_ext: jnp.ndarray,
@@ -46,6 +48,7 @@ def rhs(
     )
 
 
+@jax.named_scope("repro.newmark")
 def advance(state: NewmarkState, du: jnp.ndarray, q_new: jnp.ndarray, dt: float) -> NewmarkState:
     v_new = -state.v + (2.0 / dt) * du
     a_new = -state.a - (4.0 / dt) * state.v + (4.0 / dt**2) * du

@@ -44,6 +44,7 @@ def _tiny(x: jnp.ndarray) -> float:
     return float(jnp.finfo(x.dtype).tiny)
 
 
+@jax.named_scope("repro.pcg")
 def pcg(
     matvec: Callable[[jnp.ndarray], jnp.ndarray],
     b: jnp.ndarray,
@@ -83,6 +84,7 @@ def pcg(
     return CGResult(x=x, iters=it, relres=relres, converged=relres <= tol)
 
 
+@jax.named_scope("repro.fcg_outer")
 def fcg(
     matvec: Callable[[jnp.ndarray], jnp.ndarray],
     b: jnp.ndarray,
@@ -137,6 +139,7 @@ def make_inner_pcg_preconditioner(
     flexible outer CG absorbs the rest.
     """
 
+    @jax.named_scope("repro.fcg_inner")
     def apply(r: jnp.ndarray) -> jnp.ndarray:
         r32 = r.astype(jnp.float32)
         eps = _tiny(r32)

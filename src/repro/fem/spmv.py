@@ -30,6 +30,7 @@ from repro.fem.assembly import block_entries
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("repro.bcsr_spmv")
 def bcsr_matvec(
     values: jnp.ndarray,  # [9*nnzb] BCSR 3×3 values (assembly.assemble_bcsr)
     rowids: np.ndarray,   # [nnzb] sorted
@@ -39,14 +40,16 @@ def bcsr_matvec(
     """y[i] = Σ_j A[i,j] x[j] with 3×3 blocks (gather + sorted scatter-add),
     one flat vector per component so nothing is laid out with a minor 3."""
     cols, rows = jnp.asarray(col_idx), jnp.asarray(rowids)
-    xj = [x[:, b][cols] for b in range(3)]             # 3 × [nnzb]
-    y = [
-        jnp.zeros(x.shape[0], x.dtype).at[rows].add(
-            sum(block_entries(values, 3 * a + b) * xj[b] for b in range(3)),
-            indices_are_sorted=True)
-        for a in range(3)
-    ]
-    return jnp.stack(y, axis=1)
+    with jax.named_scope("repro.bcsr_gather"):
+        xj = [x[:, b][cols] for b in range(3)]         # 3 × [nnzb]
+    with jax.named_scope("repro.bcsr_scatter"):
+        y = [
+            jnp.zeros(x.shape[0], x.dtype).at[rows].add(
+                sum(block_entries(values, 3 * a + b) * xj[b] for b in range(3)),
+                indices_are_sorted=True)
+            for a in range(3)
+        ]
+        return jnp.stack(y, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -61,6 +64,7 @@ def bcsr_matvec(
 # layouts compile about ten times slower than this form.
 
 
+@jax.named_scope("repro.ebe_gather")
 def gather_nodal(u: jnp.ndarray, mesh) -> jnp.ndarray:
     """Nodal values per element ``[10, 3, E]`` from ``u [N,3]``."""
     idx = jnp.asarray(mesh.conn.T.reshape(-1))  # (node slot, element)
@@ -68,6 +72,7 @@ def gather_nodal(u: jnp.ndarray, mesh) -> jnp.ndarray:
     return jnp.stack([u[:, a][idx].reshape(quad.NNODE, E) for a in range(3)], axis=1)
 
 
+@jax.named_scope("repro.ebe_scatter")
 def scatter_nodal(f: jnp.ndarray, mesh) -> jnp.ndarray:
     """Σ of element contributions ``f [10, 3, E]`` per node → ``[N,3]``, by
     a sorted segment-sum per component (the atomic-add replacement:
@@ -142,7 +147,9 @@ def ebe_matvec(
     E = mesh.n_elem
     uT = gather_nodal(x, mesh).reshape(quad.NDOF, E)
     kern = element_kernel or ebe_element_lanes
-    fT = kern(uT, D, jnp.asarray(mesh.Jinv, x.dtype), jnp.asarray(mesh.wdet, x.dtype), coef_e)
+    with jax.named_scope("repro.ebe_element"):
+        fT = kern(uT, D, jnp.asarray(mesh.Jinv, x.dtype), jnp.asarray(mesh.wdet, x.dtype),
+                  coef_e)
     return scatter_nodal(fT.reshape(quad.NNODE, 3, E), mesh)
 
 
@@ -185,6 +192,7 @@ def point_tables(mesh, dtype) -> PointTables:
     )
 
 
+@jax.named_scope("repro.point_strain")
 def strain_at_points(u: jnp.ndarray, pts: PointTables) -> jnp.ndarray:
     """Total strain at all evaluation points ``[E*P, 6]`` (multispring
     input); engineering shear, as the B-matrices."""
@@ -195,6 +203,7 @@ def strain_at_points(u: jnp.ndarray, pts: PointTables) -> jnp.ndarray:
                       H[0][1] + H[1][0], H[1][2] + H[2][1], H[2][0] + H[0][2]]).T
 
 
+@jax.named_scope("repro.point_force")
 def internal_force(sigma_pts: jnp.ndarray, pts: PointTables) -> jnp.ndarray:
     """Assembled internal force q ``[N,3]`` from point stresses ``[E*P,6]``:
     q[node, i] = Σ_q Σ_j σ_ij(q) wdet(q) ∂N/∂x_j, sorted segment-sums."""

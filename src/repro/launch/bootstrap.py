@@ -33,6 +33,9 @@ _CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 # src/repro/launch/bootstrap.py → the checkout root
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
+# what enable_compile_cache sets so that the key holds op names, not locations
+CACHE_KEY_CONFIG = {"jax_compilation_cache_include_metadata_in_key": True,
+                    "jax_traceback_in_locations_limit": 0}
 
 
 def enable_compile_cache() -> str:
@@ -41,11 +44,22 @@ def enable_compile_cache() -> str:
     it is set (JAX reads the variable itself; no other directory is set),
     else a fixed ``.jax_cache/`` at the root of the checkout — fixed,
     because the path is part of what the cache matches on.  Entry points
-    call this; nothing does at import time."""
-    if os.environ.get(_CACHE_ENV):
-        return os.environ[_CACHE_ENV]
+    call this; nothing does at import time.
+
+    The key holds each instruction's op name, the path of named scopes
+    that a device profile reads, and no source location.  JAX's default key
+    leaves all metadata out, so two builds that differ only in their scopes
+    would share entries, and an executable loaded from the cache keeps the
+    op names of the build that compiled it: a profile would name layers the
+    code no longer has.  The programs carry no file, line or caller stack,
+    so a moved line, another launcher or another install path still finds
+    the entry."""
     import jax  # noqa: PLC0415 (deliberate lazy import, see module docstring)
 
+    for name, value in CACHE_KEY_CONFIG.items():
+        jax.config.update(name, value)
+    if os.environ.get(_CACHE_ENV):
+        return os.environ[_CACHE_ENV]
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -143,8 +143,15 @@ Flags
     wave sample so the health machinery above has something to catch.
     Plain campaign path only; the spec is part of the wave data and hence
     the campaign signature.
+``--profile-dir DIR``
+    Run the campaign under ``jax.profiler.trace(DIR)``: the device's
+    operations, named by the program's ``repro.*`` scopes, and the host's
+    ``repro.campaign.chunk / fetch / bank / checkpoint`` spans on one clock
+    (docs/campaign_runbook.md, "Profiling a campaign").  Plain campaign
+    path only.
 """
 import argparse
+import contextlib
 import os
 import sys
 
@@ -238,6 +245,9 @@ def main(argv=None):
                          "e.g. 'nan_at_step=5,case=1' poisons one bedrock "
                          "wave sample mid-campaign (plain campaign path "
                          "only) — the chaos-smoke rehearsal knob")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="write a profiler trace of the campaign to DIR "
+                         "(plain campaign path only)")
     # multi-host topology (parsed pre-jax-import by parse_distributed; kept
     # here so --help documents them and argparse accepts them)
     ap.add_argument("--coordinator", default=None,
@@ -320,15 +330,19 @@ def main(argv=None):
         waves = faults.apply_wave_fault(inject, waves)
         print(f"{tag} [inject] {inject.describe()}")
     obs = mesh.surface[len(mesh.surface) // 2 : len(mesh.surface) // 2 + 1]
-    res = run_campaign(
-        mesh, sim, waves, observe=obs,
-        campaign=CampaignConfig(
-            kset=args.kset, method=args.method, seed=args.seed,
-            checkpoint_dir=args.ckpt_dir, checkpoint_every=args.ckpt_every,
-        ),
-        device_mesh=dmesh,
-        stop_after_steps=args.stop_after_steps,
-    )
+    with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+          else contextlib.nullcontext()):
+        res = run_campaign(
+            mesh, sim, waves, observe=obs,
+            campaign=CampaignConfig(
+                kset=args.kset, method=args.method, seed=args.seed,
+                checkpoint_dir=args.ckpt_dir, checkpoint_every=args.ckpt_every,
+            ),
+            device_mesh=dmesh,
+            stop_after_steps=args.stop_after_steps,
+        )
+    if args.profile_dir:
+        print(f"{tag} profile written under {args.profile_dir}")
     if res.resumed_from is not None:
         print(f"{tag} [resume] from checkpoint step {res.resumed_from}")
     if not res.completed:
@@ -392,6 +406,9 @@ def _refuse_scenario_only_flags(args, tag) -> None:
     if args.trajectories:
         raise SystemExit(f"{tag} --trajectories rides the plain campaign "
                          f"path; drop --scenario/--sweep/--scenarios")
+    if args.profile_dir:
+        raise SystemExit(f"{tag} --profile-dir rides the plain campaign path; "
+                         f"drop --scenario/--sweep/--scenarios")
     if args.inject:
         raise SystemExit(f"{tag} --inject rides the plain campaign path "
                          f"(scenario sweeps generate their own waves); "

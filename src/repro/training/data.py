@@ -4,8 +4,7 @@ structure, background prefetch, and device placement by sharding.
 The bigram-chain generator gives the convergence tests something a model can
 actually learn (loss must drop below the unigram entropy); the uniform
 stream is for pure-throughput benchmarks.  ``Prefetcher`` overlaps host
-batch synthesis with device compute — the data-pipeline half of straggler
-mitigation (training/elastic.py watches its latency).
+batch synthesis with device compute.
 """
 from __future__ import annotations
 
@@ -79,7 +78,6 @@ class Prefetcher:
         self._it = it
         self._shardings = shardings
         self._stop = threading.Event()
-        self._last_wait_s = 0.0
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -99,19 +97,10 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        import time
-
-        t0 = time.perf_counter()
         item = self._q.get()
-        self._last_wait_s = time.perf_counter() - t0
         if item is None:
             raise StopIteration
         return item
-
-    @property
-    def last_wait_s(self) -> float:
-        """Input-bound stall time for the straggler watchdog."""
-        return self._last_wait_s
 
     def close(self):
         self._stop.set()

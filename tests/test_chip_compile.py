@@ -92,3 +92,30 @@ def test_multispring_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kset
         fn = jax.vmap(fn, in_axes=(0, 0, 0, None, None))
     _compile_on_v5e(fn, sds((P, 6)), state, params,
                     sds((S, 6), batched=False), sds((S,), batched=False))
+
+
+def test_stream_blocks_keep_their_scopes_through_the_v5e_compiler(one_chip,
+                                                                 no_persistent_cache):
+    """Proposed 1's host-streamed step compiled for a v5e, at a small mesh:
+    each spring-state block's compute still carries its
+    ``repro.stream_block<j>`` scope in the optimized program, which is what
+    a chip profile reads."""
+    import re
+
+    from repro.fem import backend, meshgen, methods
+
+    mesh = meshgen.generate(2, 2, 2, pad_elems_to=8)
+    cfg = methods.SeismicConfig(dt=0.01, tol=1e-6, maxiter=50, npart=2, nspring=12,
+                                dtype=jnp.float32, backend="jnp")
+    ops = backend.make_operators(mesh, cfg, platform="tpu")
+    step, _ = methods.make_step("proposed1", ops, offload=True)
+    carry = methods.initial_carry(ops, streamed=True, host=True)
+    spec = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip.with_memory_kind(x.sharding.memory_kind)),
+        carry)
+    f_t = jax.ShapeDtypeStruct((3,), jnp.float32, sharding=one_chip)
+    text = jax.jit(step).lower(spec, f_t).compile().as_text()
+    chains = {tuple(re.findall(r"repro\.[\w.]+", n))
+              for n in re.findall(r'op_name="([^"]*)"', text)}
+    for j in range(2):
+        assert (f"repro.stream_block{j}", "repro.multispring") in chains, j

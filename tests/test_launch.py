@@ -73,9 +73,11 @@ def test_cpu_pool_runs_without_jax_platforms(tmp_path, monkeypatch, capsys):
 
 @pytest.fixture
 def restore_cache_config():
-    prev = jax.config.jax_compilation_cache_dir
+    names = ["jax_compilation_cache_dir", *bootstrap.CACHE_KEY_CONFIG]
+    prev = {n: getattr(jax.config, n) for n in names}
     yield
-    jax.config.update("jax_compilation_cache_dir", prev)
+    for n, v in prev.items():
+        jax.config.update(n, v)
 
 
 def test_compile_cache_honours_the_environment(monkeypatch, tmp_path,
@@ -96,3 +98,26 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch,
     assert jax.config.jax_compilation_cache_dir == want
     with open(os.path.join(repo, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+def test_compile_cache_keys_on_op_names_not_on_source_locations(
+        monkeypatch, tmp_path, restore_cache_config):
+    """The program text the key hashes holds the named scopes and no file,
+    line or caller: another caller finds the same entry, another scope not."""
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    bootstrap.enable_compile_cache()
+
+    def lowered(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) + 1
+        return jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+
+    def from_another_caller(scope):
+        return lowered(scope)
+
+    assert lowered("repro.a") == from_another_caller("repro.a")
+    assert lowered("repro.a") != lowered("repro.b")
+    assert "repro.a" in lowered("repro.a")

@@ -191,12 +191,11 @@ def test_elastic_plan_relayout_exact():
     assert elastic_plan(32, 5, 2) == elastic_plan(32, 8, 2)
 
 
-def test_prefetcher_delivers_and_reports_wait():
+def test_prefetcher_delivers():
     dcfg = data_mod.DataConfig(vocab_size=64, seq_len=8, global_batch=2)
     pf = data_mod.Prefetcher(data_mod.batches(dcfg), depth=2)
     b = next(pf)
     assert b["tokens"].shape == (2, 8)
-    assert pf.last_wait_s >= 0.0
     pf.close()
 
 
