@@ -9,7 +9,7 @@ Kuhn subdivision.  All arrays are numpy; consumers move them to jax.
 Produces everything the four solution methods need:
   * geometry (``Jinv``, ``detJ``, ``wdet``) for EBE on-the-fly B-matrices,
   * BCSR 3×3 sparsity + element→nnz scatter map for the CRS path,
-  * sorted scatter permutation for TPU-deterministic segment-sum assembly,
+  * valence-bucketed gather tables for scatter-free nodal assembly,
   * HRZ lumped mass, Lysmer dashpot coefficients, bedrock input-force map.
 """
 from __future__ import annotations
@@ -63,6 +63,74 @@ MEDIUM = Material(rho=1800.0, vs=220.0, vp=1550.0, gamma_r=1.2e-3, beta=1.0, h_m
 BEDROCK = Material(rho=2100.0, vs=420.0, vp=1800.0, gamma_r=5e-3, beta=1.0, h_max=0.10)
 
 
+MAX_BUCKETS = 8  # distinct valences kept before rare ones are merged upward
+
+
+@dataclasses.dataclass(frozen=True)
+class ScatterTables:
+    """Nodal assembly of the element output ``f [30, E]`` (row ``3n+a``) by
+    gathers: no scatter-add.
+
+    Nodes are grouped into buckets by valence (element slots touching them).
+    ``slots[b]`` is ``[3, v, n_v]``: for component ``a`` and node ``j`` of
+    bucket ``b``, the ``v`` positions ``(3n+a)·E + e`` of its contributions
+    in ``f.reshape(-1)``, padded with ``30·E``, one zero lane appended to
+    ``f``.  Summing over the short ``v`` axis gives the bucket's nodal sums
+    with the nodes on lanes; ``order`` takes the buckets' concatenation back
+    to node numbering.
+    """
+
+    slots: tuple[np.ndarray, ...]  # per bucket [3, v, n_v] int32
+    order: np.ndarray              # [N] int32: node id → position in the buckets
+    n_buckets: int
+    pad_share: float               # padded slots ÷ real slots
+
+    @property
+    def padded(self) -> bool:
+        return self.pad_share > 0.0
+
+
+def scatter_tables(conn: np.ndarray, n_nodes: int) -> ScatterTables:
+    """Valence-bucketed gather tables for ``conn [E, 10]``.
+
+    One bucket per distinct valence; only where a mesh has more than
+    ``MAX_BUCKETS`` of them, the valence whose merge into the next larger
+    one pads the fewest slots is merged, until ``MAX_BUCKETS`` remain.
+    Ghost elements (``conn`` row of zeros) count as node 0's contributions.
+    """
+    E = conn.shape[0]
+    flat = conn.T.ravel().astype(np.int64)  # slot n*E + e → node
+    valence = np.bincount(flat, minlength=n_nodes)
+    caps, counts = np.unique(valence, return_counts=True)
+    caps, counts = list(caps), list(counts)
+    while len(caps) > MAX_BUCKETS:
+        cost = [(caps[i + 1] - caps[i]) * counts[i] for i in range(len(caps) - 1)]
+        i = int(np.argmin(cost))
+        caps.pop(i)
+        n = counts.pop(i)
+        counts[i] += n  # into the next larger valence
+    cap = np.asarray(caps)[np.searchsorted(caps, valence)]      # [N] bucket capacity
+    by_node = np.argsort(flat, kind="stable")                    # slots grouped by node
+    first = np.concatenate([[0], np.cumsum(valence)[:-1]])
+    # position of component 0 in f.reshape(-1): (3n)·E + e for slot s = n·E + e
+    pos = by_node + 2 * (by_node // E) * E
+    pad = quad.NDOF * E
+    slots, members = [], []
+    for c in caps:
+        nodes = np.flatnonzero(cap == c)
+        r = np.arange(c)[:, None]
+        real = r < valence[nodes][None]
+        p0 = pos[np.minimum(first[nodes][None] + r, flat.size - 1)]
+        slots.append(np.stack([np.where(real, p0 + a * E, pad) for a in range(3)])
+                     .astype(np.int32))
+        members.append(nodes)
+    order = np.empty(n_nodes, np.int32)
+    order[np.concatenate(members)] = np.arange(n_nodes, dtype=np.int32)
+    n_real = int(valence.sum())
+    n_slot = int(sum(c * len(m) for c, m in zip(caps, members)))
+    return ScatterTables(tuple(slots), order, len(caps), (n_slot - n_real) / n_real)
+
+
 @dataclasses.dataclass
 class Mesh:
     coords: np.ndarray        # [N,3] float64
@@ -75,8 +143,7 @@ class Mesh:
     wdet: np.ndarray          # [E,P]
     # scatter maps
     elem_dofs: np.ndarray     # [E,30] int32
-    node_perm: np.ndarray     # [10*E] int32 argsort of conn.T.ravel()
-    node_segids: np.ndarray   # [10*E] int32 sorted node ids
+    scatter: ScatterTables    # nodal assembly of the lane-major [30,E] element output
     # BCSR 3x3 (node blocks)
     row_ptr: np.ndarray       # [N+1] int32
     col_idx: np.ndarray       # [nnzb] int32
@@ -219,9 +286,7 @@ def generate(
 
     # --- scatter maps
     elem_dofs = (3 * conn[:, :, None] + np.arange(3)[None, None]).reshape(E, 30).astype(np.int32)
-    flat = conn.T.ravel()  # (node slot, element): the lane-major order
-    node_perm = np.argsort(flat, kind="stable").astype(np.int32)
-    node_segids = flat[node_perm].astype(np.int32)
+    scatter = scatter_tables(conn, coords.shape[0])
 
     # --- BCSR (node-block) sparsity from real (unpadded) elements
     ii = np.repeat(conn[:E0], 10, axis=1).ravel()
@@ -249,8 +314,7 @@ def generate(
         detJ=detJ,
         wdet=wdet,
         elem_dofs=elem_dofs,
-        node_perm=node_perm,
-        node_segids=node_segids,
+        scatter=scatter,
         row_ptr=row_ptr,
         col_idx=cols,
         rowids=rows,

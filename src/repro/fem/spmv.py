@@ -2,9 +2,12 @@
 
 The paper's Proposed Method 2 converts the memory-bandwidth-bound CRS SpMV
 into on-the-fly element products (EBE, [8]) — more FLOPs, far less memory
-traffic, no stored matrix.  TPU adaptation (DESIGN.md §8): the scatter-add
-that CUDA does with L2 atomics becomes a *sorted segment-sum* over a
-precomputed permutation (deterministic, TPU-idiomatic).
+traffic, no stored matrix.  TPU adaptation (DESIGN.md §8, §10): the
+scatter-add that CUDA does with L2 atomics becomes a *gather-form assembly*:
+nodes grouped by valence, each group's contributions gathered into a
+lane-dense ``[v, n_v]`` table and summed over its short leading axis
+(``meshgen.scatter_tables``).  A TPU runs gathers at memory speed but an
+XLA scatter-add close to one update at a time; the sum is deterministic.
 
 The jnp implementations here are the reference path; kernels/ebe_matvec
 holds the Pallas kernel for the per-element contraction (the flop hotspot),
@@ -73,16 +76,24 @@ def gather_nodal(u: jnp.ndarray, mesh) -> jnp.ndarray:
 
 
 @jax.named_scope("repro.ebe_scatter")
-def scatter_nodal(f: jnp.ndarray, mesh) -> jnp.ndarray:
-    """Σ of element contributions ``f [10, 3, E]`` per node → ``[N,3]``, by
-    a sorted segment-sum per component (the atomic-add replacement:
-    deterministic, TPU-idiomatic)."""
-    perm, seg = jnp.asarray(mesh.node_perm), jnp.asarray(mesh.node_segids)
-    return jnp.stack([
-        jax.ops.segment_sum(f[:, a].reshape(-1)[perm], seg,
-                            num_segments=mesh.n_nodes, indices_are_sorted=True)
-        for a in range(3)
-    ], axis=1)
+def scatter_nodal(fT: jnp.ndarray, tables) -> jnp.ndarray:
+    """Σ of element contributions ``fT [30, E]`` (row ``3n+a``, the element
+    kernel's output) per node → ``[N,3]``, by gathers and sums: for each
+    valence bucket of :class:`meshgen.ScatterTables`, the ``[v, n_v]``
+    contributions summed over ``v`` in a fixed order, then one gather back
+    to node numbering."""
+    flat = fT.reshape(-1)
+    if tables.padded:  # padded slots point at one zero lane past the end
+        flat = jnp.concatenate([flat, jnp.zeros(1, flat.dtype)])
+    order = jnp.asarray(tables.order)
+    out = []
+    for a in range(3):
+        with jax.named_scope("repro.ebe_scatter_sum"):
+            sums = jnp.concatenate([flat[jnp.asarray(t[a])].sum(axis=0)
+                                    for t in tables.slots])
+        with jax.named_scope("repro.ebe_scatter_order"):
+            out.append(sums[order])
+    return jnp.stack(out, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +149,7 @@ def ebe_matvec(
     coef_e: jnp.ndarray | None = None,
     element_kernel=None,
 ) -> jnp.ndarray:
-    """Full matrix-free K·x (gather → element product → sorted scatter).
+    """Full matrix-free K·x (gather → element product → gather-form scatter).
 
     ``element_kernel`` (lane-major: ``[30,E]`` in and out, the
     ``ebe_element_lanes`` convention) lets the Pallas kernel replace the jnp
@@ -150,7 +161,7 @@ def ebe_matvec(
     with jax.named_scope("repro.ebe_element"):
         fT = kern(uT, D, jnp.asarray(mesh.Jinv, x.dtype), jnp.asarray(mesh.wdet, x.dtype),
                   coef_e)
-    return scatter_nodal(fT.reshape(quad.NNODE, 3, E), mesh)
+    return scatter_nodal(fT, mesh.scatter)
 
 
 # ---------------------------------------------------------------------------

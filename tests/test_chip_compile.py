@@ -119,3 +119,34 @@ def test_stream_blocks_keep_their_scopes_through_the_v5e_compiler(one_chip,
               for n in re.findall(r'op_name="([^"]*)"', text)}
     for j in range(2):
         assert (f"repro.stream_block{j}", "repro.multispring") in chains, j
+
+
+def test_ebe_product_has_no_scatter_under_its_scatter_scope(one_chip, no_persistent_cache):
+    """The EBE product of ``ebe-k2-resident`` (22³ hexes, E = 63,888, kset 2
+    vmap, the Pallas element kernel) compiled for a v5e: its nodal assembly
+    is gathers and sums, so no ``scatter`` instruction carries the
+    ``repro.ebe_scatter`` scope, and the product fits one chip."""
+    import functools
+    import re
+
+    from repro.fem import meshgen, spmv
+
+    mesh = meshgen.generate(22, 22, 22, pad_elems_to=8)
+    E22, N = mesh.n_elem, mesh.n_nodes
+    assert E22 == 63_888
+    kern = functools.partial(ebe_element_lanes_pallas, tile_e=512, interpret=False)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct((2,) + shape, jnp.float32, sharding=one_chip)
+
+    fn = jax.vmap(lambda x, D, c: spmv.ebe_matvec(x, D, mesh, c, element_kernel=kern))
+    compiled = jax.jit(fn).lower(sds(N, 3), sds(E22, quad.NPOINT, 6, 6), sds(E22)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    scoped = [ln for ln in text.splitlines() if "repro.ebe_scatter" in ln]
+    assert any(re.search(r" gather\(", ln) for ln in scoped)
+    assert not [ln for ln in scoped if re.search(r" scatter\(", ln)]
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total} bytes do not fit one v5e"
